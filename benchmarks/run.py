@@ -3,7 +3,7 @@
   fig2a  bench_train_batchsize   training throughput vs batch size
   fig2b  bench_inference         inference throughput + streaming row
   fig2c  bench_accuracy          MNIST-proxy accuracy (BCPNN + hybrid)
-  fig2d  bench_scaling           strong scaling (fake multi-device)
+  fig2d  bench_scaling           strong scaling (meshes over this process's devices)
   fig3   bench_precision         BF14..BF28 accuracy cliff
   sec4.3 bench_stl10             STL-10-scale run
   issue4 bench_deep              depth sweep: project-once vs fused phases

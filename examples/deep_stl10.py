@@ -18,6 +18,7 @@ real 27648-feature STL-10 shape on CPU in a few minutes.
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import (
     DenseLayer,
     ExecutionConfig,
@@ -58,6 +59,7 @@ def phase_seconds(history):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny dimensions for CI (seconds, not minutes)")

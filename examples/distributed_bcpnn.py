@@ -26,6 +26,8 @@ from repro.core import (  # noqa: E402
 )
 from repro.core.distributed import DataParallelTrainer  # noqa: E402
 from repro.data import complementary_code, mnist_like  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def build(layout):
@@ -38,6 +40,7 @@ def build(layout):
 
 
 def main():
+    enable_compile_cache()
     print(f"devices: {len(jax.devices())}")
     ds = mnist_like(n_train=512, n_test=64, n_features=64, seed=0)
     x, layout = complementary_code(ds.x_train)
@@ -50,7 +53,7 @@ def main():
 
     # (b)+(c) same model, 4-way data x 2-way model mesh — only the config
     # changes; the trainer decorates the execution plan.
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     for mode in ("shard_map", "pjit"):
         trainer = DataParallelTrainer(mesh, mode=mode)
         compiled = build(layout).compile(ExecutionConfig(trainer=trainer))

@@ -9,6 +9,7 @@ BF14..BF28 through the full BCPNN datapath and prints the accuracy curve —
 reproducing the paper's finding that BCPNN tolerates BF16 with minor loss
 while BF14 collapses to chance.
 """
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import (
     DenseLayer, ExecutionConfig, Network, StructuralPlasticityLayer,
     UnitLayout, onehot_layout,
@@ -18,6 +19,7 @@ from repro.precision import FORMATS
 
 
 def main():
+    enable_compile_cache()
     ds = mnist_like(n_train=2048, n_test=512, n_features=64, seed=0)
     x_tr, layout = complementary_code(ds.x_train)
     x_te, _ = complementary_code(ds.x_test)

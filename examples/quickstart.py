@@ -10,6 +10,7 @@ The model description is purely declarative; everything about execution
 """
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import (
     DenseLayer,
     ExecutionConfig,
@@ -23,6 +24,7 @@ from repro.data import complementary_code, mnist_like
 
 
 def main():
+    enable_compile_cache()
     # 1. Data: continuous features in [0,1], complementary-coded into 2-MCU
     #    input hypercolumns (x, 1-x).
     ds = mnist_like(n_train=4096, n_test=1024, n_features=64, seed=0)

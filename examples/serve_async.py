@@ -17,6 +17,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import ARCH_NAMES, get_smoke_config
 from repro.models import build_model
 from repro.runtime import (
@@ -28,6 +29,7 @@ from repro.runtime import (
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=[a for a in ARCH_NAMES], default="yi-9b")
     ap.add_argument("--requests", type=int, default=8)

@@ -15,12 +15,14 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import ARCH_NAMES, get_smoke_config
 from repro.models import build_model
 from repro.runtime import Request, ServiceConfig, serve_model
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=[a for a in ARCH_NAMES], default="yi-9b")
     ap.add_argument("--requests", type=int, default=6)

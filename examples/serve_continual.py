@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import (
     DenseLayer,
     ExecutionConfig,
@@ -69,6 +70,7 @@ def build_fitted(seed=0):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced stream for CI (default sizes are small "

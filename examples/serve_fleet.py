@@ -23,6 +23,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import ARCH_NAMES, get_smoke_config
 from repro.models import build_model
 from repro.runtime import (
@@ -37,6 +38,7 @@ from repro.runtime import (
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_NAMES), default="gemma3-1b")
     ap.add_argument("--fleet", type=int, default=2)

@@ -16,12 +16,14 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core import ExecutionConfig, Network, StructuralPlasticityLayer, UnitLayout
 from repro.data import complementary_code, mnist_like
 from repro.runtime import ServiceConfig
 
 
 def main():
+    enable_compile_cache()
     ds = mnist_like(n_train=1024, n_test=64, n_features=64, seed=0)
     x, layout = complementary_code(ds.x_train)
 

@@ -15,6 +15,7 @@ import dataclasses
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.data import lm_batches, token_stream
 from repro.models import build_model
@@ -30,6 +31,7 @@ PRESETS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=PRESETS, default="tiny")
     ap.add_argument("--steps", type=int, default=30)

@@ -108,7 +108,7 @@ _TRACE_WRAPPERS = {
     "jax.grad", "grad",
     "jax.value_and_grad", "value_and_grad",
     "jax.checkpoint", "jax.remat",
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map", "jax.shard_map",
     "checkify.checkify",
 }
 

@@ -28,8 +28,7 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import learning
 from repro.core.layers import DenseLayer, LayerState, StructuralPlasticityLayer
@@ -87,7 +86,14 @@ class DataParallelTrainer:
     def __init__(self, mesh: Mesh, mode: str = "shard_map"):
         if mode not in ("shard_map", "pjit"):
             raise ValueError(f"mode must be shard_map|pjit, got {mode}")
-        self.mesh = mesh
+        # The steps write global math and leave the all-reduce to the
+        # compiler (implicit sharding), so every mesh axis must be Auto;
+        # jax.make_mesh's default Explicit axes refuse the batch-contracting
+        # einsum.  Same devices, same layout, Auto axes.
+        self.mesh = Mesh(
+            mesh.devices, mesh.axis_names,
+            axis_types=(AxisType.Auto,) * len(mesh.axis_names),
+        )
         self.mode = mode
         self.baxes = batch_axes(mesh)
         if not self.baxes:
@@ -139,7 +145,8 @@ class DataParallelTrainer:
         """Device-put a layer state with the trainer's shardings."""
         spec = self._state_spec(layer, self._can_shard_hidden(layer))
         return jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
+            lambda x, s: x if x is None
+            else jax.device_put(x, NamedSharding(self.mesh, s)),
             state,
             spec,
             is_leaf=lambda x: x is None,
@@ -162,7 +169,7 @@ class DataParallelTrainer:
         sspec = self._state_spec(layer, self._can_shard_hidden(layer))
         s_shard = jax.tree_util.tree_map(
             lambda s: NamedSharding(self.mesh, s), sspec,
-            is_leaf=lambda x: isinstance(x, P) or x is None,
+            is_leaf=lambda x: isinstance(x, P),
         )
         x_shard = self.batch_sharding()
         y_shard = NamedSharding(self.mesh, P(self.baxes))
@@ -222,20 +229,20 @@ class DataParallelTrainer:
             return LayerState(marg, w, b, state.plast, state.step + 1)
 
         if supervised:
-            fn = shard_map(
+            fn = jax.shard_map(
                 local_step,
                 mesh=self.mesh,
                 in_specs=(sspec, x_spec, P(baxes)),
                 out_specs=sspec,
-                check_rep=False,
+                check_vma=False,
             )
         else:
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda s, xb: local_step(s, xb),
                 mesh=self.mesh,
                 in_specs=(sspec, x_spec),
                 out_specs=sspec,
-                check_rep=False,
+                check_vma=False,
             )
 
         if (

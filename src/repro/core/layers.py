@@ -155,7 +155,7 @@ def _learn(
 
             marg, w, b = kops.bcpnn_update(
                 marg, ai, aj, lam=spec.lam, k_b=spec.k_b, mask=mask,
-                state_format=sfmt, layout=spec.post,
+                state_format=sfmt,
             )
         else:
             if sfmt is not None:
@@ -185,8 +185,7 @@ def _fused_train_batch(
 ) -> Tuple[LayerState, jnp.ndarray]:
     """The one-dispatch training path: the whole Alg.1 batch iteration
     (forward + HCU softmax + EWMA marginals + weight/bias epilogue) in a
-    single `bcpnn_phase` Pallas call, bit-exact with the unfused kernel
-    composition."""
+    single `bcpnn_phase` Pallas call."""
     from repro.kernels import ops as kops
 
     mask = (

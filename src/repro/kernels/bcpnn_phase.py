@@ -10,30 +10,27 @@ to the three-dispatch composition (`masked_matmul` -> gain -> `hcu_softmax`
 s and the activations a_j, and fuses the optional `bf_round` state
 quantization into the epilogue instead of running it as a separate op.
 
+Hypercolumn lane layout: inside the kernel every HCU owns whole 128-lane
+groups — its n_mcu units followed by lane padding up to
+``hcu_lanes(n_mcu)``.  The wrapper moves the H axis of w / C_ij / mask /
+b / c_j into that layout and back.  So every H tile is a multiple of 128
+lanes, an HCU never spans two tiles, and the softmax reads each HCU as an
+aligned lane slice (no lane-splitting reshape, which Mosaic refuses).
+
 Grid layout: ``(H_tiles, T)`` with the phase counter ``t`` innermost and
 ``T = F_tiles + 1 + F_tiles * B_chunks``.  For a fixed output tile column j:
 
   t in [0, nf)      forward: s_acc (scratch, full padded batch resident)
-                    accumulates x_tile @ (w_tile * mask_tile) over F tiles —
-                    the exact K-chunk order of `masked_matmul`;
-  t == nf           softmax: bias add + gain, per-HCU softmax with MCU lanes
-                    padded to the same 128-wide -inf layout as `hcu_softmax`,
-                    padded batch rows zeroed; writes the a_j block (which
-                    stays resident for the update steps);
+                    accumulates x_tile @ (w_tile * mask_tile) over F tiles;
+  t == nf           softmax: bias add + gain, per-HCU softmax over its lane
+                    group with the pad lanes at -inf, padded batch rows
+                    zeroed; writes the a_j block (which stays resident for
+                    the update steps);
   t > nf            update: step (i, c) = divmod(t - nf - 1, nb) processes
-                    batch chunk c of F tile i with the *same per-step
-                    expressions and block shapes* as the `bcpnn_update`
-                    kernel grid; the epilogue at c == nb-1 applies state
-                    rounding and the masked Bayes weights.
+                    batch chunk c of F tile i; the epilogue at c == nb-1
+                    applies state rounding and the masked Bayes weights.
 
-Bit-exactness with the unfused kernel path requires replicating not just the
-accumulation *order* but the exact per-step expression shapes: XLA's fusion
-(FMA contraction, reduction vectorization) is context-sensitive, so a batch
-chunk folded into a static in-kernel loop does NOT produce the same bits as
-the same chunk processed as its own grid step.  Hence the update region is
-step-per-(F tile, batch chunk), mirroring `bcpnn_update`'s grid, and the H
-tile is hypercolumn-aligned in BOTH kernels (see ops.py).  λ, B, k_B, gain
-and the state mantissa width are compile-time constants.
+λ, B, k_B, gain and the state mantissa width are compile-time constants.
 """
 from __future__ import annotations
 
@@ -48,13 +45,52 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.bf_round import rne_round
 
 EPS = 1e-8
+LANES = 128
+# Widest H tile: at B=256, F tile 128 the double-buffered w / C_ij / mask
+# inputs, the C_ij' / w' outputs, the a_j block and the s scratch take
+# about 5 MiB, well inside v5e's 16 MiB of scoped VMEM.
+MAX_TILE_LANES = 512
 
 
-def hcu_block_h(n_mcu: int, h: int) -> int:
-    """Hypercolumn-aligned H tile (~128 lanes): the softmax reduction must
-    never span tile boundaries, and the unfused `bcpnn_update` must use the
-    SAME tile for the fused/unfused paths to be bit-exact."""
-    return min(h, n_mcu * max(1, 128 // n_mcu))
+def hcu_lanes(n_mcu: int) -> int:
+    """Lanes one HCU occupies in the kernel layout: n_mcu rounded up to
+    whole 128-lane groups."""
+    return -(-n_mcu // LANES) * LANES
+
+
+def hcus_per_tile(n_hcu: int, n_mcu: int) -> int:
+    """HCUs per H tile: the largest divisor of n_hcu whose tile stays within
+    MAX_TILE_LANES (one HCU when a single HCU is already wider)."""
+    mp = hcu_lanes(n_mcu)
+    return max(
+        d for d in range(1, n_hcu + 1)
+        if n_hcu % d == 0 and (d == 1 or d * mp <= MAX_TILE_LANES)
+    )
+
+
+def to_hcu_lanes(a, n_hcu: int, n_mcu: int, fill: float = 0.0):
+    """(..., n_hcu*n_mcu) -> (..., n_hcu*hcu_lanes(n_mcu)), pad lanes at
+    ``fill``."""
+    mp = hcu_lanes(n_mcu)
+    if mp == n_mcu:
+        return a
+    lead = a.shape[:-1]
+    a = a.reshape(*lead, n_hcu, n_mcu)
+    a = jnp.pad(
+        a, [(0, 0)] * (len(lead) + 1) + [(0, mp - n_mcu)], constant_values=fill
+    )
+    return a.reshape(*lead, n_hcu * mp)
+
+
+def from_hcu_lanes(a, n_hcu: int, n_mcu: int):
+    """Inverse of :func:`to_hcu_lanes`: drop the pad lanes."""
+    mp = hcu_lanes(n_mcu)
+    if mp == n_mcu:
+        return a
+    lead = a.shape[:-1]
+    return a.reshape(*lead, n_hcu, mp)[..., :n_mcu].reshape(
+        *lead, n_hcu * n_mcu
+    )
 
 
 def _kernel(
@@ -98,34 +134,33 @@ def _kernel(
         )
 
     # ---- softmax phase (t == nf): a_j, kept resident for the update ----
+    # Each HCU is one aligned group of mp lanes (see hcu_lanes); its pad
+    # lanes go to -inf, so exp() gives them zero mass.
     @pl.when(t == nf)
     def _():
-        s = s_acc[...] + bias_ref[...].astype(jnp.float32)
-        if gain != 1.0:
-            s = s * gain
-        bp, ht = s.shape
-        hcu_t = ht // n_mcu
-        x = s.reshape(bp, hcu_t, n_mcu)
-        if mp > n_mcu:  # -inf lane pad: exp(-inf)=0 keeps the sums exact
-            x = jnp.concatenate(
-                [x, jnp.full((bp, hcu_t, mp - n_mcu), -jnp.inf, jnp.float32)],
-                axis=-1,
-            )
-        m = jnp.max(x, axis=-1, keepdims=True)
-        e = jnp.exp(x - m)
-        z = jnp.sum(e, axis=-1, keepdims=True)
-        a = (e / z)[:, :, :n_mcu].reshape(bp, ht)
+        bp, ht = s_acc.shape
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bp, mp), 1)
         # Padded batch rows went through the softmax as garbage; zero them so
         # they vanish from the means and the outer products below.
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bp, ht), 0)
-        aj_ref[...] = jnp.where(rows < b_real, a, 0.0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (bp, mp), 0)
+        for g in range(ht // mp):
+            cols = slice(g * mp, (g + 1) * mp)
+            s = s_acc[:, cols] + bias_ref[:, cols].astype(jnp.float32)
+            if gain != 1.0:
+                s = s * gain
+            s = jnp.where(lane < n_mcu, s, -jnp.inf)
+            e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            a = e / jnp.sum(e, axis=-1, keepdims=True)
+            aj_ref[:, cols] = jnp.where(row < b_real, a, 0.0)
 
     # ---- update phase (t > nf): EWMA marginals + weight epilogue ----
-    # Per-step shapes and expressions mirror the bcpnn_update kernel exactly.
     @pl.when(t > nf)
     def _():
         ai = ai_ref[...].astype(jnp.float32)            # (bt, ft)
-        aj = aj_ref[pl.ds(c * bt, bt), :]               # (bt, ht) f32
+        if nb == 1:
+            aj = aj_ref[...]                            # (bt, ht) f32
+        else:
+            aj = aj_ref[pl.ds(pl.multiple_of(c * bt, bt), bt), :]
 
         # Chunk 0: seed the accumulators with the decayed old marginals.
         # cij/ci blocks are revisited per j (recomputed identically); the
@@ -211,32 +246,37 @@ def bcpnn_phase_fused(
     ``state_mantissa``) is applied in the epilogue, storage-dtype casts are
     the wrapper's (ops.py) job.
 
-    Padding: batch and F with zeros, H to whole *fake hypercolumns* (w/bias
-    zero, marginals 1.0 so the logs stay finite); fake-HCU softmax columns
-    produce uniform non-zero activations but only feed padded C_ij/w columns,
-    which are sliced off.
+    Padding: batch and F with zeros; H into the hypercolumn lane layout
+    (:func:`to_hcu_lanes`) with w/bias/mask zero and marginals 1.0 on the
+    pad lanes, so the logs stay finite.  The softmax gives pad lanes zero
+    activation, and they are dropped on the way out.
     """
     bsz, f = x.shape
-    h = n_hcu * n_mcu
-    ft = min(128, f)
+    ft = min(LANES, f)
     fp = -(-f // ft) * ft
     nf = fp // ft
-    ht = hcu_block_h(n_mcu, h)
-    hp = -(-h // ht) * ht
+    mp = hcu_lanes(n_mcu)
+    hp = n_hcu * mp
+    ht = hcus_per_tile(n_hcu, n_mcu) * mp
     bt = min(128, bsz)
     bp = -(-bsz // bt) * bt
     nb = bp // bt
-    mp = max(128, -(-n_mcu // 128) * 128)  # softmax lane pad, as hcu_softmax
+
+    def lanes(a, fill=0.0):
+        return to_hcu_lanes(a, n_hcu, n_mcu, fill)
+
+    def pad_f(a, fill=0.0):
+        return jnp.pad(a, ((0, fp - f), (0, 0)), constant_values=fill)
 
     x_p = jnp.pad(x, ((0, bp - bsz), (0, fp - f)))
-    w_p = jnp.pad(w, ((0, fp - f), (0, hp - h)))
-    b_p = jnp.pad(b, (0, hp - h)).reshape(1, hp)
-    cij_p = jnp.pad(cij, ((0, fp - f), (0, hp - h)), constant_values=1.0)
+    w_p = pad_f(lanes(w))
+    b_p = lanes(b).reshape(1, hp)
+    cij_p = pad_f(lanes(cij, 1.0), 1.0)
     ci_p = jnp.pad(ci, (0, fp - f), constant_values=1.0).reshape(1, fp)
-    cj_p = jnp.pad(cj, (0, hp - h), constant_values=1.0).reshape(1, hp)
+    cj_p = lanes(cj, 1.0).reshape(1, hp)
     has_mask = mask is not None
     mask_p = (
-        jnp.pad(mask.astype(jnp.float32), ((0, fp - f), (0, hp - h)))
+        pad_f(lanes(mask.astype(jnp.float32)))
         if has_mask
         else jnp.ones((1, 1), jnp.float32)  # dummy operand, never read
     )
@@ -297,10 +337,10 @@ def bcpnn_phase_fused(
         interpret=interpret,
     )(x_p, x_p, w_p, b_p, cij_p, ci_p, cj_p, mask_p)
     return (
-        aj[:bsz, :h],
+        from_hcu_lanes(aj[:bsz], n_hcu, n_mcu),
         ci_n[0, :f],
-        cj_n[0, :h],
-        cij_n[:f, :h],
-        w_n[:f, :h],
-        bias_n[0, :h],
+        from_hcu_lanes(cj_n[0], n_hcu, n_mcu),
+        from_hcu_lanes(cij_n[:f], n_hcu, n_mcu),
+        from_hcu_lanes(w_n[:f], n_hcu, n_mcu),
+        from_hcu_lanes(bias_n[0], n_hcu, n_mcu),
     )

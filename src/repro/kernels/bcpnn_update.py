@@ -27,17 +27,9 @@ are RNE-rounded in the epilogue — fused `bf_round`, not a separate op — and
 w/bias are derived from the rounded traces.  λ, B, k_B are compile-time
 constants (λ changes never inside a run).
 
-Grid layout: ``(H_tiles, 1 + F_tiles * B_chunks)`` with a phase counter t
-innermost; t == 0 is a structural no-op and step t > 0 processes
-(i, c) = divmod(t - 1, nb).  This deliberately mirrors the update region of
-the fused `bcpnn_phase` kernel statement for statement (same pl.when
-nesting, same per-step shapes, same in-branch expression order): XLA's
-fusion and FMA-contraction decisions are sensitive to cond structure and to
-which grid dimensions constant-fold away, so the two kernels only produce
-bit-identical marginals when their compiled update bodies are structurally
-identical.  The t == 0 no-op keeps the phase counter a dynamic loop variable
-even for single-tile shapes (a fully-folded (1, 1, 1) grid compiles the seed
-and epilogue inline and flips low bits).
+Grid layout: ``(H_tiles, F_tiles * B_chunks)`` with the step counter t
+innermost; step t processes batch chunk c of F tile i, (i, c) =
+divmod(t, nb).
 """
 from __future__ import annotations
 
@@ -54,7 +46,6 @@ EPS = 1e-8
 
 
 def _kernel(
-    nf: int,
     nb: int,
     b_real: int,
     lam: float,
@@ -66,66 +57,63 @@ def _kernel(
 ):
     t = pl.program_id(1)
     one_m = 1.0 - lam
-    upd = t - 1
-    i = upd // nb   # F tile of the update step (valid when t > 0)
-    c = upd % nb    # batch chunk of the update step (floor-mod, ditto)
+    i = t // nb   # F tile
+    c = t % nb    # batch chunk
 
-    @pl.when(t > 0)
+    ai = ai_ref[...].astype(jnp.float32)  # (bt, ft)
+    aj = aj_ref[...].astype(jnp.float32)  # (bt, ht)
+
+    # Chunk 0: seed the accumulators with the decayed old marginals.
+    # cij/ci blocks are revisited per j (recomputed identically); the
+    # cj/bias blocks stay resident for the whole j sweep, so cj is
+    # seeded/accumulated only during F tile 0's chunk sweep.
+    @pl.when(c == 0)
     def _():
-        ai = ai_ref[...].astype(jnp.float32)  # (bt, ft)
-        aj = aj_ref[...].astype(jnp.float32)  # (bt, ht)
+        cij_out_ref[...] = one_m * cij_ref[...].astype(jnp.float32)
+        ci_out_ref[...] = one_m * ci_ref[...].astype(jnp.float32)
 
-        # Chunk 0: seed the accumulators with the decayed old marginals.
-        # cij/ci blocks are revisited per j (recomputed identically); the
-        # cj/bias blocks stay resident for the whole j sweep, so cj is
-        # seeded/accumulated only during F tile 0's chunk sweep.
-        @pl.when(c == 0)
-        def _():
-            cij_out_ref[...] = one_m * cij_ref[...].astype(jnp.float32)
-            ci_out_ref[...] = one_m * ci_ref[...].astype(jnp.float32)
+    @pl.when((c == 0) & (i == 0))
+    def _():
+        cj_out_ref[...] = one_m * cj_ref[...].astype(jnp.float32)
 
-        @pl.when((c == 0) & (i == 0))
-        def _():
-            cj_out_ref[...] = one_m * cj_ref[...].astype(jnp.float32)
+    # MXU: contraction over the batch chunk; VPU: batch-mean row-sums.
+    cij_out_ref[...] += (lam * inv_b) * jax.lax.dot_general(
+        ai, aj, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ci_out_ref[...] += lam * (jnp.sum(ai, axis=0, keepdims=True) / b_real)
 
-        # MXU: contraction over the batch chunk; VPU: batch-mean row-sums.
-        cij_out_ref[...] += (lam * inv_b) * jax.lax.dot_general(
-            ai, aj, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    @pl.when(i == 0)
+    def _():
+        cj_out_ref[...] += lam * (
+            jnp.sum(aj, axis=0, keepdims=True) / b_real
         )
-        ci_out_ref[...] += lam * (jnp.sum(ai, axis=0, keepdims=True) / b_real)
 
-        @pl.when(i == 0)
-        def _():
-            cj_out_ref[...] += lam * (
-                jnp.sum(aj, axis=0, keepdims=True) / b_real
-            )
-
-        # Last chunk: (optional) state rounding + Bayes weight epilogue on
-        # the resident tiles.
-        @pl.when(c == nb - 1)
-        def _():
-            ci = ci_out_ref[...]
-            cj = cj_out_ref[...]
-            cij_new = cij_out_ref[...]
-            if state_mantissa is not None:
-                ci = rne_round(ci, state_mantissa)
-                cj = rne_round(cj, state_mantissa)  # idempotent for i > 0
-                cij_new = rne_round(cij_new, state_mantissa)
-                cij_out_ref[...] = cij_new
-                ci_out_ref[...] = ci
-
-                @pl.when(i == 0)
-                def _():
-                    cj_out_ref[...] = cj
+    # Last chunk: (optional) state rounding + Bayes weight epilogue on
+    # the resident tiles.
+    @pl.when(c == nb - 1)
+    def _():
+        ci = ci_out_ref[...]
+        cj = cj_out_ref[...]
+        cij_new = cij_out_ref[...]
+        if state_mantissa is not None:
+            ci = rne_round(ci, state_mantissa)
+            cj = rne_round(cj, state_mantissa)  # idempotent for i > 0
+            cij_new = rne_round(cij_new, state_mantissa)
+            cij_out_ref[...] = cij_new
+            ci_out_ref[...] = ci
 
             @pl.when(i == 0)
             def _():
-                bias_ref[...] = k_b * jnp.log(jnp.maximum(cj, EPS))
+                cj_out_ref[...] = cj
 
-            log_ci = jnp.log(jnp.maximum(ci, EPS)).reshape(ci.shape[1], 1)
-            log_cj = jnp.log(jnp.maximum(cj, EPS))  # (1, ht)
-            w = jnp.log(jnp.maximum(cij_new, EPS)) - log_ci - log_cj
-            w_ref[...] = (w * mask_ref[...].astype(jnp.float32)).astype(w_ref.dtype)
+        @pl.when(i == 0)
+        def _():
+            bias_ref[...] = k_b * jnp.log(jnp.maximum(cj, EPS))
+
+        log_ci = jnp.log(jnp.maximum(ci, EPS)).reshape(ci.shape[1], 1)
+        log_cj = jnp.log(jnp.maximum(cj, EPS))  # (1, ht)
+        w = jnp.log(jnp.maximum(cij_new, EPS)) - log_ci - log_cj
+        w_ref[...] = (w * mask_ref[...].astype(jnp.float32)).astype(w_ref.dtype)
 
 
 @functools.partial(
@@ -176,18 +164,18 @@ def bcpnn_update_fused(
 
     nb = bp // bt
     nf = fp // ft
-    grid = (hp // ht, 1 + nf * nb)  # no-op step 0 + per-(F tile, chunk) steps
+    grid = (hp // ht, nf * nb)  # one step per (F tile, batch chunk)
 
     def upd_i(t):
-        return jnp.clip((t - 1) // nb, 0, nf - 1)
+        return t // nb
 
     def upd_c(t):
-        return jnp.where(t > 0, (t - 1) % nb, 0)
+        return t % nb
 
     # jaxlint: allow[JL001] reason=lam/k_b are in static_argnames — Python floats at trace time, not device values
     lam_f, kb_f = float(lam), float(k_b)
     kernel = functools.partial(
-        _kernel, nf, nb, b, lam_f, 1.0 / b, kb_f, state_mantissa
+        _kernel, nb, b, lam_f, 1.0 / b, kb_f, state_mantissa
     )
     cij_n, w, ci_n, cj_n, bias = pl.pallas_call(
         kernel,

@@ -35,6 +35,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import SHAPES, batch_specs, decode_specs, get_config, shape_applicable
 from repro.configs.registry import ARCH_NAMES
 from repro.launch.mesh import make_production_mesh
@@ -361,6 +362,7 @@ def probe_suite(arch: str, shape_name: str):
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

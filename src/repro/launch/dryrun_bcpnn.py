@@ -104,14 +104,15 @@ def run(multi_pod: bool, out_dir: str, n_f=55296, n_hcu=512, n_mcu=256,
             v = getattr(mem, attr, None)
             if v is not None:
                 rec[attr] = int(v)
-    # Roofline terms (no scans -> direct).
-    from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+    # Roofline terms (no scans -> direct), projected for a v5e pod.
+    from repro.launch.mesh import V5E, peaks
     from repro.launch.roofline import WIRE_WEIGHT
 
+    chip = peaks(V5E)
     wire = sum(coll.get(op, 0.0) * w for op, w in WIRE_WEIGHT.items())
-    rec["compute_term_s"] = rec["flops_per_device"] / PEAK_FLOPS_BF16
-    rec["memory_term_s"] = rec["bytes_per_device"] / HBM_BW
-    rec["collective_term_s"] = wire / ICI_BW
+    rec["compute_term_s"] = rec["flops_per_device"] / chip.bf16_flops
+    rec["memory_term_s"] = rec["bytes_per_device"] / chip.hbm_bw
+    rec["collective_term_s"] = wire / chip.ici_bw
     rec["useful_flop_ratio"] = model_flops / (
         rec["flops_per_device"] * rec["chips"]
     )
@@ -132,6 +133,9 @@ def run(multi_pod: bool, out_dir: str, n_f=55296, n_hcu=512, n_mcu=256,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", choices=("pod", "multipod", "both"), default="both")
     ap.add_argument("--out", default="experiments/dryrun")

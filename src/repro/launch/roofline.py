@@ -22,9 +22,10 @@ full global batch with n_micro=1, so flops/collective volume equal the
 production step exactly; the microbatch loop's extra weight re-reads are
 added analytically to the bytes term.
 
-Terms (TPU v5e, per chip): compute = flops/197e12, memory = bytes/819e9,
-collective = collective_bytes/50e9.  All per-device (equivalent to the
-global-total / (chips x rate) form for uniform sharding).
+Terms are projected for a TPU v5e chip (``peaks(V5E)`` in launch/mesh.py):
+compute = flops / bf16 peak, memory = bytes / HBM bandwidth, collective =
+collective_bytes / interconnect bandwidth.  All per-device (equivalent to
+the global-total / (chips x rate) form for uniform sharding).
 """
 from __future__ import annotations
 
@@ -35,7 +36,7 @@ import os
 from typing import Dict, List, Optional, Tuple
 
 from repro.configs import SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, peaks
 
 METRICS = ("flops_per_device", "bytes_per_device", "coll_total")
 
@@ -229,6 +230,7 @@ def analyze_cell(dryrun_dir: str, arch: str, shape_name: str, tag: str = "") -> 
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     chips = prod.get("chips", 256)
+    chip = peaks(V5E)
 
     flops = extrapolate(probes, cfg, shape, "flops_per_device")
     bytes_ = extrapolate(probes, cfg, shape, "bytes_per_device")
@@ -261,12 +263,12 @@ def analyze_cell(dryrun_dir: str, arch: str, shape_name: str, tag: str = "") -> 
     )
     rec["analytic_hbm_bytes"] = analytic_mem
     if flops is not None:
-        rec["compute_term_s"] = flops / PEAK_FLOPS_BF16
-    rec["memory_term_s"] = analytic_mem / HBM_BW
+        rec["compute_term_s"] = flops / chip.bf16_flops
+    rec["memory_term_s"] = analytic_mem / chip.hbm_bw
     if bytes_ is not None:
-        rec["memory_hlo_upper_s"] = bytes_ / HBM_BW
+        rec["memory_hlo_upper_s"] = bytes_ / chip.hbm_bw
     if coll is not None:
-        rec["collective_term_s"] = coll / ICI_BW
+        rec["collective_term_s"] = coll / chip.ici_bw
     terms = {
         k: rec.get(k)
         for k in ("compute_term_s", "memory_term_s", "collective_term_s")
@@ -294,7 +296,7 @@ def analyze_cell(dryrun_dir: str, arch: str, shape_name: str, tag: str = "") -> 
             )
         else:
             # Achievable-model-compute time / dominant-term bound.
-            model_compute_s = rec["model_flops"] / (chips * PEAK_FLOPS_BF16)
+            model_compute_s = rec["model_flops"] / (chips * chip.bf16_flops)
             rec["roofline_fraction"] = (
                 model_compute_s / step_time if step_time else None
             )

@@ -44,6 +44,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.models import build_model
 from repro.runtime import (
@@ -146,6 +147,7 @@ def parse_tenants(spec):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_NAMES), default="gemma3-1b")
     ap.add_argument("--requests", type=int, default=4)

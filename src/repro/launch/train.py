@@ -1,7 +1,9 @@
 """Production training launcher.
 
     python -m repro.launch.train --arch yi-9b --steps 100 [--smoke]
-    python -m repro.launch.train --arch bcpnn --steps 20
+
+Language models only; BCPNN networks train through
+``Network.compile(...).fit`` (see examples/ and chip_smoke.py).
 
 On the container this runs the reduced (smoke) configs on CPU; on a real
 pod the same entry point runs the full config with the production mesh
@@ -17,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.data import lm_batches, token_stream
 from repro.models import build_model
@@ -26,6 +29,7 @@ from repro.sharding.rules import ShardCtx, param_shardings
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_NAMES), required=True)
     ap.add_argument("--steps", type=int, default=20)

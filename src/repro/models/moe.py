@@ -29,7 +29,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import Params, dense_init
@@ -215,7 +214,7 @@ def _moe_psum(params, x, cfg, ctx: ShardCtx):
             aux = jax.lax.pmean(aux, baxes)
         return out.reshape(x_l.shape), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=ctx.mesh,
         in_specs=(
@@ -226,7 +225,7 @@ def _moe_psum(params, x, cfg, ctx: ShardCtx):
             P("model", None, None),
         ),
         out_specs=(P(baxes, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, params["router"], params["gate"], params["up"], params["down"])
 
@@ -279,7 +278,7 @@ def _moe_a2a(params, x, cfg, ctx: ShardCtx):
         aux = jax.lax.pmean(aux, baxes + ("model",) if baxes else "model")
         return out.reshape(x_l.shape), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=ctx.mesh,
         in_specs=(
@@ -290,7 +289,7 @@ def _moe_a2a(params, x, cfg, ctx: ShardCtx):
             P("model", None, None),
         ),
         out_specs=(P(baxes, "model", None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     x_sp = ctx.cs(x, "batch", "sp_seq", None)  # reshard: seq over model
     out, aux = fn(x_sp, params["router"], params["gate"], params["up"], params["down"])

@@ -99,8 +99,10 @@ def epoch_sharding(trainer, ndim: int) -> Optional[NamedSharding]:
 # --------------------------------------------------------------------------
 # Epoch-scan builders.  Each returns a jitted function closed over the layer
 # *structure* (static) and taking all traced state explicitly, with the
-# mutable carry and the epoch buffers donated — re-running an epoch reuses
-# the same compiled program.
+# mutable carry donated — re-running an epoch reuses the same compiled
+# program.  The stacked epoch inputs are not donated: no output has their
+# shape, so the buffer could not be reused and jax would warn at every
+# compile.
 # --------------------------------------------------------------------------
 def _donate(enabled: bool, *argnums: int) -> dict:
     """donate_argnums kwargs, suppressed on CPU (donation unsupported there
@@ -147,7 +149,7 @@ def hidden_epoch_fn(
         state, _ = jax.lax.scan(body, state, xs)
         return state
 
-    return jax.jit(epoch, **_donate(donate, 0, 2))
+    return jax.jit(epoch, **_donate(donate, 0))
 
 
 def readout_epoch_fn(
@@ -171,7 +173,7 @@ def readout_epoch_fn(
         state, _ = jax.lax.scan(body, state, (xs, ys))
         return state
 
-    return jax.jit(epoch, **_donate(donate, 0, 2, 3))
+    return jax.jit(epoch, **_donate(donate, 0))
 
 
 def sgd_epoch_fn(
@@ -196,7 +198,7 @@ def sgd_epoch_fn(
         )
         return params, opt_state, losses
 
-    return jax.jit(epoch, **_donate(donate, 0, 1, 3, 4))
+    return jax.jit(epoch, **_donate(donate, 0, 1))
 
 
 # --------------------------------------------------------------------------
@@ -222,7 +224,7 @@ def hidden_epoch_cached_fn(
         state, _ = jax.lax.scan(body, state, xs)
         return state
 
-    return jax.jit(epoch, **_donate(donate, 0, 1))
+    return jax.jit(epoch, **_donate(donate, 0))
 
 
 def readout_epoch_cached_fn(
@@ -242,7 +244,7 @@ def readout_epoch_cached_fn(
         state, _ = jax.lax.scan(body, state, (hs, ys))
         return state
 
-    return jax.jit(epoch, **_donate(donate, 0, 1, 2))
+    return jax.jit(epoch, **_donate(donate, 0))
 
 
 def sgd_epoch_cached_fn(opt, loss_fn: Callable, donate: bool = True) -> Callable:
@@ -263,4 +265,4 @@ def sgd_epoch_cached_fn(opt, loss_fn: Callable, donate: bool = True) -> Callable
         )
         return params, opt_state, losses
 
-    return jax.jit(epoch, **_donate(donate, 0, 1, 2, 3))
+    return jax.jit(epoch, **_donate(donate, 0, 1))
