@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.core.layers import DenseLayer, LayerState, StructuralPlasticityLayer
 from repro.runtime.plans import PLANS, ExecutionPlan, make_plan
+from repro.runtime.trace import span
 
 READOUTS = ("bcpnn", "sgd")
 
@@ -150,14 +151,17 @@ class ExecutionConfig:
                  after every epoch.  Guards sit at phase entry/exit only, so
                  steady-state throughput is unchanged.
     trace:       optional repro.runtime.trace.TraceConfig — the compiled
-                 network owns a Tracer and the phase programs record
-                 ``train.<phase>`` spans (host vs device-wait attribution)
-                 on the shared training trace id.  None (default) keeps
-                 every span site a dead ``is not None`` check.
+                 network owns a Tracer and training records its spans
+                 there on the shared training trace id: ``train.fit``,
+                 ``train.<phase>`` per epoch (host vs device-wait
+                 attribution), and inside each epoch ``train.gather``,
+                 ``train.upload``, ``train.dispatch`` and ``train.fence``.
+                 The same spans are always profiler annotations; None
+                 (default) records nothing else.
     profile_dir: when set, ``fit()`` runs its whole phase program under
                  ``jax.profiler.trace(profile_dir)`` — a device-level
-                 profile (TensorBoard/Perfetto) complementing the
-                 host-side phase spans.
+                 profile (TensorBoard/Perfetto) in which the ``train.*``
+                 spans appear beside the device's ops.
     """
 
     engine: str = "scan"
@@ -306,6 +310,7 @@ class CompiledNetwork:
         from repro.runtime.trace import build_tracer
 
         self.tracer = build_tracer(self.config.trace)
+        self.plan.tracer = self.tracer
 
     # ------------------------------------------------------------ structure
     @property
@@ -421,12 +426,12 @@ class CompiledNetwork:
             if self.config.profile_dir is not None
             else contextlib.nullcontext()
         )
-        with profile:
+        with profile, span(self.tracer, "train.fit"):
             self._run(
                 dataset, epochs_hidden, epochs_readout, batch_size, readout,
                 readout_lr, shuffle, verbose, history, reset_readout=True,
             )
-        self._strict_check("fit")
+            self._strict_check("fit")
         return FitResult(
             epochs_hidden=epochs_hidden,
             epochs_readout=epochs_readout,
@@ -458,12 +463,13 @@ class CompiledNetwork:
 
         t0 = time.perf_counter()
         history: List[dict] = []
-        self._run(
-            dataset, 1, 1 if readout is not None else 0, batch_size,
-            readout or "bcpnn", readout_lr, shuffle, verbose, history,
-            reset_readout=False,
-        )
-        self._strict_check("partial_fit")
+        with span(self.tracer, "train.fit"):
+            self._run(
+                dataset, 1, 1 if readout is not None else 0, batch_size,
+                readout or "bcpnn", readout_lr, shuffle, verbose, history,
+                reset_readout=False,
+            )
+            self._strict_check("partial_fit")
         return FitResult(
             epochs_hidden=1,
             epochs_readout=1 if readout is not None else 0,

@@ -45,12 +45,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.runtime.trace import span
+
 
 def stack_epoch(
     arr,
     idx: np.ndarray,
     batch_size: int,
     sharding: Optional[NamedSharding] = None,
+    tracer=None,
 ) -> jnp.ndarray:
     """Gather a shuffled epoch and reshape to ``(n_batches, B, ...)``.
 
@@ -60,18 +63,24 @@ def stack_epoch(
     gather with ``jnp.take`` instead, so the epoch never round-trips through
     host memory.  ``idx`` must already be trimmed to a multiple of
     ``batch_size``.
+
+    The two steps run under the ``train.gather`` and ``train.upload`` spans
+    (:func:`repro.runtime.trace.span`; ``tracer`` may be None).
     """
     n = idx.shape[0]
     if n % batch_size != 0:
         raise ValueError(f"epoch of {n} samples is not a multiple of B={batch_size}")
     shape = (n // batch_size, batch_size, *arr.shape[1:])
-    if isinstance(arr, jax.Array):
-        stacked = jnp.take(arr, jnp.asarray(idx), axis=0).reshape(shape)
-        return jax.device_put(stacked, sharding) if sharding is not None else stacked
-    stacked = np.ascontiguousarray(arr[idx]).reshape(shape)
-    if sharding is not None:
-        return jax.device_put(stacked, sharding)
-    return jnp.asarray(stacked)
+    on_device = isinstance(arr, jax.Array)
+    with span(tracer, "train.gather"):
+        if on_device:
+            stacked = jnp.take(arr, jnp.asarray(idx), axis=0).reshape(shape)
+        else:
+            stacked = np.ascontiguousarray(arr[idx]).reshape(shape)
+    with span(tracer, "train.upload"):
+        if sharding is not None:
+            return jax.device_put(stacked, sharding)
+        return stacked if on_device else jnp.asarray(stacked)
 
 
 def gather_batch(arr, sel: np.ndarray) -> jnp.ndarray:
@@ -102,7 +111,9 @@ def epoch_sharding(trainer, ndim: int) -> Optional[NamedSharding]:
 # mutable carry donated — re-running an epoch reuses the same compiled
 # program.  The stacked epoch inputs are not donated: no output has their
 # shape, so the buffer could not be reused and jax would warn at every
-# compile.
+# compile.  Each inner function has a name of its own, so each program
+# compiles, caches and profiles as ``jit_<name>`` (``jit_hidden_epoch``,
+# ``jit_readout_epoch_cached``, ...).
 # --------------------------------------------------------------------------
 def _donate(enabled: bool, *argnums: int) -> dict:
     """donate_argnums kwargs, suppressed on CPU (donation unsupported there
@@ -142,14 +153,14 @@ def hidden_epoch_fn(
         lambda s, xb: layer.train_batch(s, xb)[0]
     )
 
-    def epoch(state, below_states, xs):
+    def hidden_epoch(state, below_states, xs):
         def body(carry, xb):
             return step(carry, below(below_states, xb)), None
 
         state, _ = jax.lax.scan(body, state, xs)
         return state
 
-    return jax.jit(epoch, **_donate(donate, 0))
+    return jax.jit(hidden_epoch, **_donate(donate, 0))
 
 
 def readout_epoch_fn(
@@ -165,7 +176,7 @@ def readout_epoch_fn(
         lambda s, hb, yb: layer.train_batch(s, hb, yb)[0]
     )
 
-    def epoch(state, hidden_states, xs, ys):
+    def readout_epoch(state, hidden_states, xs, ys):
         def body(carry, batch):
             xb, yb = batch
             return step(carry, below(hidden_states, xb), yb), None
@@ -173,7 +184,7 @@ def readout_epoch_fn(
         state, _ = jax.lax.scan(body, state, (xs, ys))
         return state
 
-    return jax.jit(epoch, **_donate(donate, 0))
+    return jax.jit(readout_epoch, **_donate(donate, 0))
 
 
 def sgd_epoch_fn(
@@ -183,7 +194,7 @@ def sgd_epoch_fn(
     (params, opt_state, losses)`` for one hybrid-readout (AdamW) epoch."""
     below = forward_stack(hidden_layers)
 
-    def epoch(params, opt_state, hidden_states, xs, ys):
+    def sgd_epoch(params, opt_state, hidden_states, xs, ys):
         def body(carry, batch):
             p, s = carry
             xb, yb = batch
@@ -198,7 +209,7 @@ def sgd_epoch_fn(
         )
         return params, opt_state, losses
 
-    return jax.jit(epoch, **_donate(donate, 0, 1))
+    return jax.jit(sgd_epoch, **_donate(donate, 0, 1))
 
 
 # --------------------------------------------------------------------------
@@ -217,14 +228,14 @@ def hidden_epoch_cached_fn(
         lambda s, xb: layer.train_batch(s, xb)[0]
     )
 
-    def epoch(state, xs):
+    def hidden_epoch_cached(state, xs):
         def body(carry, xb):
             return step(carry, xb), None
 
         state, _ = jax.lax.scan(body, state, xs)
         return state
 
-    return jax.jit(epoch, **_donate(donate, 0))
+    return jax.jit(hidden_epoch_cached, **_donate(donate, 0))
 
 
 def readout_epoch_cached_fn(
@@ -236,7 +247,7 @@ def readout_epoch_cached_fn(
         lambda s, hb, yb: layer.train_batch(s, hb, yb)[0]
     )
 
-    def epoch(state, hs, ys):
+    def readout_epoch_cached(state, hs, ys):
         def body(carry, batch):
             hb, yb = batch
             return step(carry, hb, yb), None
@@ -244,14 +255,14 @@ def readout_epoch_cached_fn(
         state, _ = jax.lax.scan(body, state, (hs, ys))
         return state
 
-    return jax.jit(epoch, **_donate(donate, 0))
+    return jax.jit(readout_epoch_cached, **_donate(donate, 0))
 
 
 def sgd_epoch_cached_fn(opt, loss_fn: Callable, donate: bool = True) -> Callable:
     """Jitted ``(params, opt_state, hs, ys) -> (params, opt_state, losses)``:
     one hybrid-readout (AdamW) epoch on pre-projected hidden codes."""
 
-    def epoch(params, opt_state, hs, ys):
+    def sgd_epoch_cached(params, opt_state, hs, ys):
         def body(carry, batch):
             p, s = carry
             hb, yb = batch
@@ -265,4 +276,4 @@ def sgd_epoch_cached_fn(opt, loss_fn: Callable, donate: bool = True) -> Callable
         )
         return params, opt_state, losses
 
-    return jax.jit(epoch, **_donate(donate, 0, 1))
+    return jax.jit(sgd_epoch_cached, **_donate(donate, 0, 1))

@@ -49,6 +49,7 @@ from repro.runtime.epoch_engine import (
     sgd_epoch_fn,
     stack_epoch,
 )
+from repro.runtime.trace import span
 
 
 class ExecutionPlan:
@@ -69,6 +70,9 @@ class ExecutionPlan:
         # CompiledNetwork can assert each one compiles exactly once.
         self.jitted: dict = {}
         self.trainer = None
+        # The compiled network's Tracer (or None): the scan plan records its
+        # train.gather / upload / dispatch spans there.
+        self.tracer = None
         self._hidden_cache: dict = {}
         self._hidden_step_cache: dict = {}
         self._readout_cache: Optional[Callable] = None
@@ -163,8 +167,20 @@ class ScanPlan(ExecutionPlan):
 
     def _stack(self, arr, idx, batch_size):
         return stack_epoch(
-            arr, idx, batch_size, epoch_sharding(self.trainer, arr.ndim + 1)
+            arr, idx, batch_size, epoch_sharding(self.trainer, arr.ndim + 1),
+            tracer=self.tracer,
         )
+
+    def _dispatch(self, epoch_fn, *args):
+        """``epoch_fn(*args)`` under the ``train.dispatch`` span, whose
+        ``traces`` attr counts the entries the call added to the callable's
+        trace cache (0 once it is compiled)."""
+        before = epoch_fn._cache_size()
+        with span(self.tracer, "train.dispatch") as attrs:
+            with dispatch_guard(self.strict):
+                out = epoch_fn(*args)
+            attrs["traces"] = epoch_fn._cache_size() - before
+        return out
 
     def place_state(self, layer, state):
         if self.trainer is not None:
@@ -183,8 +199,7 @@ class ScanPlan(ExecutionPlan):
 
             def run(state, below_states, x, idx, batch_size):
                 xs = self._stack(x, idx, batch_size)
-                with dispatch_guard(self.strict):
-                    return epoch_fn(state, below_states, xs)
+                return self._dispatch(epoch_fn, state, below_states, xs)
 
             self._hidden_cache[li] = run
         return run
@@ -202,8 +217,7 @@ class ScanPlan(ExecutionPlan):
             def run(state, hidden_states, x, y, idx, batch_size):
                 xs = self._stack(x, idx, batch_size)
                 ys = self._stack(y, idx, batch_size)
-                with dispatch_guard(self.strict):
-                    return epoch_fn(state, hidden_states, xs, ys)
+                return self._dispatch(epoch_fn, state, hidden_states, xs, ys)
 
             self._readout_cache = run
         return self._readout_cache
@@ -217,10 +231,9 @@ class ScanPlan(ExecutionPlan):
         def run(params, opt_state, hidden_states, x, y, idx, batch_size):
             xs = self._stack(x, idx, batch_size)
             ys = self._stack(y, idx, batch_size)
-            with dispatch_guard(self.strict):
-                params, opt_state, losses = epoch_fn(
-                    params, opt_state, hidden_states, xs, ys
-                )
+            params, opt_state, losses = self._dispatch(
+                epoch_fn, params, opt_state, hidden_states, xs, ys
+            )
             return params, opt_state, losses[-1]
 
         return run
@@ -238,8 +251,7 @@ class ScanPlan(ExecutionPlan):
 
             def run(state, xk, idx, batch_size):
                 xs = self._stack(xk, idx, batch_size)
-                with dispatch_guard(self.strict):
-                    return epoch_fn(state, xs)
+                return self._dispatch(epoch_fn, state, xs)
 
             self._hidden_cache[("cached", li)] = run
         return run
@@ -256,8 +268,7 @@ class ScanPlan(ExecutionPlan):
             def run(state, hk, y, idx, batch_size):
                 hs = self._stack(hk, idx, batch_size)
                 ys = self._stack(y, idx, batch_size)
-                with dispatch_guard(self.strict):
-                    return epoch_fn(state, hs, ys)
+                return self._dispatch(epoch_fn, state, hs, ys)
 
             self._readout_cached = run
         return self._readout_cached
@@ -269,8 +280,9 @@ class ScanPlan(ExecutionPlan):
         def run(params, opt_state, hk, y, idx, batch_size):
             hs = self._stack(hk, idx, batch_size)
             ys = self._stack(y, idx, batch_size)
-            with dispatch_guard(self.strict):
-                params, opt_state, losses = epoch_fn(params, opt_state, hs, ys)
+            params, opt_state, losses = self._dispatch(
+                epoch_fn, params, opt_state, hs, ys
+            )
             return params, opt_state, losses[-1]
 
         return run

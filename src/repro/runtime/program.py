@@ -18,7 +18,9 @@ control flow buys three things:
   re-running the frozen stack per batch;
 * **observability** — every history entry carries a ``seconds`` field
   (epoch wall-time, blocked on the result) plus explicit ``project``
-  entries, so the phase-program speedup is measurable from the API.
+  entries, so the phase-program speedup is measurable from the API; each
+  runs under a ``train.<phase>`` span (:func:`repro.runtime.trace.span`)
+  that a JAX profile shows beside the device's ops.
 
 The driver is engine-agnostic: it calls the bound
 :class:`repro.runtime.plans.ExecutionPlan`'s cached epoch runners when the
@@ -28,12 +30,15 @@ the two paths are bit-exact (``tests/test_deep_networks.py``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
+
+from repro.runtime.trace import span
 
 
 # --------------------------------------------------------------------------
@@ -186,27 +191,38 @@ def run_program(
     return ProgramResult(sgd_params, sgd_ran, bcpnn_trained)
 
 
-def _timed(history: List[dict], entry: dict, t0: float, result, net=None) -> None:
-    """Record one history entry with its wall-time split into the host-side
-    dispatch span (``host_s``: t0 to the fence) and the device wait at the
-    phase-boundary fence (``device_wait_s``); ``seconds`` stays the total.
-    When the network carries a tracer, the entry is also recorded as a
-    ``train.<phase>`` span on the shared training trace."""
-    t1 = time.perf_counter()
-    # jaxlint: allow[JL001] reason=phase timing telemetry must block once at the phase boundary
-    jax.block_until_ready(result)
-    t2 = time.perf_counter()
-    entry["host_s"] = t1 - t0
-    entry["device_wait_s"] = t2 - t1
-    entry["seconds"] = t2 - t0
-    history.append(entry)
+@contextlib.contextmanager
+def _timed(net, history: List[dict], entry: dict):
+    """Times one epoch (or projection) as a history entry and as a
+    ``train.<phase>`` span (:func:`repro.runtime.trace.span`; recorded in
+    the network's tracer when it has one).
+
+    The body does the work and ends with ``fence(result)``, which blocks on
+    the result under the ``train.fence`` span.  The entry's wall-time is
+    split into the host-side span (``host_s``: body start to the fence) and
+    the device wait at the fence (``device_wait_s``); ``seconds`` is the
+    total and ``traces`` the number of programs the body traced."""
     tracer = getattr(net, "tracer", None)
-    if tracer is not None:
-        attrs = {
-            k: v for k, v in entry.items() if k not in ("phase", "seconds")
-        }
-        tracer.record(
-            tracer.TRAIN_TRACE_ID, f"train.{entry['phase']}", t0, t2, **attrs
+    with span(tracer, f"train.{entry['phase']}") as attrs:
+        sizes = net.plan.jit_cache_sizes
+        traces = sum(sizes().values())
+        t0 = time.perf_counter()
+
+        def fence(result) -> None:
+            t1 = time.perf_counter()
+            with span(tracer, "train.fence"):
+                # jaxlint: allow[JL001] reason=phase timing telemetry must block once at the phase boundary
+                jax.block_until_ready(result)
+            t2 = time.perf_counter()
+            entry["host_s"] = t1 - t0
+            entry["device_wait_s"] = t2 - t1
+            entry["seconds"] = t2 - t0
+            entry["traces"] = sum(sizes().values()) - traces
+
+        yield fence
+        history.append(entry)
+        attrs.update(
+            (k, v) for k, v in entry.items() if k not in ("phase", "seconds")
         )
 
 
@@ -233,10 +249,11 @@ def _phase_input(net, level: int, states, x, batch_size, history):
     store = net.activations
     if store is None:
         return None
-    t0 = time.perf_counter()
-    xk = store.level(level, states, x, chunk=batch_size)
-    if level > 0:
-        _timed(history, {"phase": "project", "level": level}, t0, xk, net=net)
+    if level == 0:
+        return x
+    with _timed(net, history, {"phase": "project", "level": level}) as fence:
+        xk = store.level(level, states, x, chunk=batch_size)
+        fence(xk)
     return xk
 
 
@@ -256,14 +273,12 @@ def _run_hidden_phase(
         below = states[:li]
         step = lambda st, idx: run_epoch(st, below, x, idx, batch_size)  # noqa: E731
     for epoch in range(phase.epochs):
-        t0 = time.perf_counter()
-        idx = net._epoch_indices(n, n_total, shuffle)
-        state = step(state, idx)
-        _check_finite(net, state, f"hidden layer {li}, epoch {epoch}")
-        _timed(
-            history, {"phase": f"hidden{li}", "epoch": epoch}, t0, state,
-            net=net,
-        )
+        entry = {"phase": f"hidden{li}", "epoch": epoch}
+        with _timed(net, history, entry) as fence:
+            idx = net._epoch_indices(n, n_total, shuffle)
+            state = step(state, idx)
+            _check_finite(net, state, f"hidden layer {li}, epoch {epoch}")
+            fence(state)
         if verbose:
             print(
                 f"[fit/{net.plan.name}] hidden layer {li} epoch "
@@ -296,11 +311,12 @@ def _run_bcpnn_phase(
             st, hidden_states, x, y, idx, batch_size
         )
     for epoch in range(phase.epochs):
-        t0 = time.perf_counter()
-        idx = net._epoch_indices(n, n_total, shuffle)
-        state = step(state, idx)
-        _check_finite(net, state, f"bcpnn readout epoch {epoch}")
-        _timed(history, {"phase": "readout", "epoch": epoch}, t0, state, net=net)
+        entry = {"phase": "readout", "epoch": epoch}
+        with _timed(net, history, entry) as fence:
+            idx = net._epoch_indices(n, n_total, shuffle)
+            state = step(state, idx)
+            _check_finite(net, state, f"bcpnn readout epoch {epoch}")
+            fence(state)
         if verbose:
             print(
                 f"[fit/{net.plan.name}] readout epoch {epoch + 1}/{phase.epochs}"
@@ -325,14 +341,12 @@ def _run_sgd_phase(
             p, s, hidden_states, x, y, idx, batch_size
         )
     for epoch in range(phase.epochs):
-        t0 = time.perf_counter()
-        idx = net._epoch_indices(n, n_total, shuffle)
-        params, opt_state, loss = step(params, opt_state, idx)
-        _check_finite(net, params, f"sgd readout epoch {epoch}")
-        _timed(
-            history, {"phase": "sgd_readout", "epoch": epoch}, t0, params,
-            net=net,
-        )
+        entry = {"phase": "sgd_readout", "epoch": epoch}
+        with _timed(net, history, entry) as fence:
+            idx = net._epoch_indices(n, n_total, shuffle)
+            params, opt_state, loss = step(params, opt_state, idx)
+            _check_finite(net, params, f"sgd readout epoch {epoch}")
+            fence(params)
         if verbose:
             print(
                 f"[fit/{net.plan.name}] sgd readout epoch "
