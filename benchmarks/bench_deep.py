@@ -48,11 +48,19 @@ def build_deep(layout, depth, seed=0):
 
 
 def phase_split(history):
-    """{phase: seconds} over training epochs + projections."""
+    """{phase: seconds} over training epochs + projections.  A ``stage``
+    entry (a host array placed on the device for a phase) is charged to the
+    phase whose epochs follow it."""
     agg = {}
+    staged = 0.0
     for h in history:
-        if "seconds" in h:
-            agg[h["phase"]] = agg.get(h["phase"], 0.0) + h["seconds"]
+        if "seconds" not in h:
+            continue
+        if h["phase"] == "stage":
+            staged += h["seconds"]
+            continue
+        agg[h["phase"]] = agg.get(h["phase"], 0.0) + h["seconds"] + staged
+        staged = 0.0
     return agg
 
 

@@ -46,9 +46,11 @@ class FitResult:
 
     ``epochs_hidden`` echoes the request: one int for every hidden layer or
     a per-layer schedule list.  ``history`` holds one entry per executed
-    epoch (``{"phase", "epoch", "seconds"}``) plus ``project`` entries for
-    each phase-boundary activation projection, so per-phase wall-time is
-    observable from the API.
+    epoch (``{"phase", "epoch", "input", "seconds"}``, ``input`` saying
+    whether the epoch was gathered on the ``"device"`` or the ``"host"``)
+    plus ``project`` entries for each phase-boundary activation projection
+    and ``stage`` entries for each host array placed on the device for a
+    phase, so per-phase wall-time is observable from the API.
     """
 
     epochs_hidden: Any

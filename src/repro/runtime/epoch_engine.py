@@ -6,9 +6,11 @@ BCPNN layers the dispatch overhead, not the MXU, dominates (the BLAS2->BLAS3
 aggregation problem StreamBrain solves with resident-state streaming).  This
 module keeps the whole Alg. 1 inner loop resident on the device:
 
-* :func:`stack_epoch` gathers a pre-shuffled epoch once on the host and
-  reshapes it to ``(n_batches, B, ...)`` so the epoch crosses the PCIe/ICI
-  boundary exactly once;
+* :func:`stack_epoch` gathers a pre-shuffled epoch in one step and
+  reshapes it to ``(n_batches, B, ...)``: on the device with ``jnp.take``
+  from an array already there (the dataset the plan staged once for the
+  phase, a ``jax.Array`` input, an activation cache), or on the host with
+  one transfer where the dataset stays on the host;
 * the ``*_epoch_fn`` builders wrap a per-batch transition into a single
   jitted, buffer-donated ``lax.scan`` over the leading batch axis — the
   hidden Hebbian phase, the BCPNN readout phase, and the SGD readout phase
@@ -38,6 +40,7 @@ epoch functions.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -46,6 +49,21 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.runtime.trace import span
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _take_epoch(arr, idx, shape, sharding):
+    """One epoch gathered on the device and laid out with ``sharding`` inside
+    the program.  A ``device_put`` of the gathered array to another sharding
+    goes through the host instead: 0.34–0.45 s an epoch at STL-10 width on
+    four v5e chips, more than the gather it follows.  The indices take the
+    stack's ``(n_batches, B)`` shape first: from a set replicated on every
+    chip that gathers straight into the epoch's layout, with half the
+    temporaries of a flat gather and a reshape (STL-10 width, v5e 2x2)."""
+    stacked = jnp.take(arr, idx.reshape(shape[:2]), axis=0)
+    if sharding is not None:
+        stacked = jax.lax.with_sharding_constraint(stacked, sharding)
+    return stacked
 
 
 def stack_epoch(
@@ -57,12 +75,15 @@ def stack_epoch(
 ) -> jnp.ndarray:
     """Gather a shuffled epoch and reshape to ``(n_batches, B, ...)``.
 
-    Host arrays: one contiguous host-side gather, one device transfer —
-    versus one transfer per batch in the per-batch loop.  Arrays already on
-    device (a ``jax.Array`` input or the device-resident activation cache)
-    gather with ``jnp.take`` instead, so the epoch never round-trips through
-    host memory.  ``idx`` must already be trimmed to a multiple of
-    ``batch_size``.
+    Arrays on the device — the dataset ``ScanPlan`` staged once for the
+    phase, a ``jax.Array`` input, a device-resident activation cache —
+    gather with ``jnp.take`` in one program that also lays the stack out
+    with ``sharding``, so the epoch never round-trips through host memory
+    (its ``train.upload`` span is then empty).  Host arrays (a dataset too
+    large to stage, a host-spilled cache level) take one contiguous
+    host-side gather and one device transfer — versus one transfer per
+    batch in the per-batch loop.
+    ``idx`` must already be trimmed to a multiple of ``batch_size``.
 
     The two steps run under the ``train.gather`` and ``train.upload`` spans
     (:func:`repro.runtime.trace.span`; ``tracer`` may be None).
@@ -74,13 +95,15 @@ def stack_epoch(
     on_device = isinstance(arr, jax.Array)
     with span(tracer, "train.gather"):
         if on_device:
-            stacked = jnp.take(arr, jnp.asarray(idx), axis=0).reshape(shape)
+            stacked = _take_epoch(arr, jnp.asarray(idx), shape, sharding)
         else:
             stacked = np.ascontiguousarray(arr[idx]).reshape(shape)
     with span(tracer, "train.upload"):
+        if on_device:
+            return stacked
         if sharding is not None:
             return jax.device_put(stacked, sharding)
-        return stacked if on_device else jnp.asarray(stacked)
+        return jnp.asarray(stacked)
 
 
 def gather_batch(arr, sel: np.ndarray) -> jnp.ndarray:

@@ -26,8 +26,10 @@ Epoch-runner calling convention (host-side data in, new state out):
     sgd_epoch(opt, loss_fn)(params, opt_state, hidden_states, x, y, idx,
                             batch_size) -> (params, opt_state, last_loss)
 
-``x``/``y`` are the full host arrays; ``idx`` is the (already length-trimmed)
-shuffled index vector for this epoch.
+``x``/``y`` are the full arrays a phase gathers from: the caller's host
+arrays, or the device copies :meth:`ScanPlan.stage` made of them once
+for the phase; ``idx`` is the (already length-trimmed) shuffled index vector
+for this epoch.
 """
 from __future__ import annotations
 
@@ -35,9 +37,12 @@ from typing import Any, Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis.strict import dispatch_guard
 from repro.runtime.epoch_engine import (
+    _take_epoch,
     epoch_sharding,
     forward_stack,
     gather_batch,
@@ -50,6 +55,18 @@ from repro.runtime.epoch_engine import (
     stack_epoch,
 )
 from repro.runtime.trace import span
+
+
+def free_device_bytes(devices) -> Optional[int]:
+    """The least ``bytes_limit - bytes_in_use`` over ``devices``, or None
+    where the backend reports no memory statistics (the CPU)."""
+    free = []
+    for d in devices:
+        stats = d.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        free.append(stats["bytes_limit"] - stats.get("bytes_in_use", 0))
+    return min(free)
 
 
 class ExecutionPlan:
@@ -77,6 +94,7 @@ class ExecutionPlan:
         self._hidden_step_cache: dict = {}
         self._readout_cache: Optional[Callable] = None
         self._readout_cached: Optional[Callable] = None
+        self._gather_bytes_cache: dict = {}
         self._plastic_cls = StructuralPlasticityLayer
         self._dense_cls = DenseLayer
 
@@ -120,6 +138,14 @@ class ExecutionPlan:
     def place_state(self, layer, state):
         """Device placement for a layer state entering this plan's epochs."""
         return state
+
+    # -------------------------------------------------------- phase input
+    def stages(self, arr, n: int, batch_size: int) -> bool:
+        """Whether a phase places the host array ``arr`` its epochs gather
+        ``n`` rows from on the device once (:meth:`ScanPlan.stage`).  The
+        base plan never does: BatchPlan's per-batch loop is the numerical
+        reference."""
+        return False
 
     # ------------------------------------------------------- single steps
     def hidden_step(self, li: int) -> Callable:
@@ -186,6 +212,67 @@ class ScanPlan(ExecutionPlan):
         if self.trainer is not None:
             return self.trainer.place_state(layer, state)
         return state
+
+    def _stage_sharding(self, arr) -> Optional[NamedSharding]:
+        """Where a staged array lives.  One chip: the default device.  Under
+        a trainer: rows sharded over the batch axes (``trainer.cache_sharding``,
+        as the activation caches), whose upload is one shard a chip; a whole
+        copy on every chip where the rows do not divide, an upload of the
+        whole set to each chip."""
+        if self.trainer is None:
+            return None
+        sharding = self.trainer.cache_sharding(arr.ndim)
+        try:
+            sharding.shard_shape(arr.shape)
+        except ValueError:
+            return NamedSharding(self.trainer.mesh, P())
+        return sharding
+
+    def stages(self, arr, n: int, batch_size: int) -> bool:
+        """Host arrays are staged whole, so every epoch of the phase gathers
+        its shuffled stack of ``n`` rows on the device (``stack_epoch``'s
+        ``jnp.take``) and the data crosses to the device once a phase, not
+        once an epoch — where the device reports room: the bytes the compiled
+        epoch gather holds on each chip (:meth:`_gather_bytes`) within half
+        of its free memory.  A backend that reports none (the CPU) stages."""
+        if isinstance(arr, jax.Array):
+            return False
+        sharding = self._stage_sharding(arr)
+        devices = jax.devices()[:1] if sharding is None else sharding.device_set
+        free = free_device_bytes(devices)
+        return free is None or self._gather_bytes(arr, n, batch_size) <= free // 2
+
+    def _gather_bytes(self, arr, n: int, batch_size: int) -> int:
+        """Per-chip bytes of the epoch gather from a staged ``arr`` at its
+        peak — the staged copy, the index vector, the stacked epoch and
+        XLA's temporaries — from the compiled program's memory analysis,
+        once a shape."""
+        key = (arr.shape, arr.dtype, n, batch_size)
+        if key not in self._gather_bytes_cache:
+            shape = (n // batch_size, batch_size) + arr.shape[1:]
+            src = jax.ShapeDtypeStruct(
+                arr.shape, jax.dtypes.canonicalize_dtype(arr.dtype),
+                sharding=self._stage_sharding(arr),
+            )
+            idx = jax.ShapeDtypeStruct(
+                (n,), jax.dtypes.canonicalize_dtype(np.int64))
+            mem = _take_epoch.lower(
+                src, idx, shape, epoch_sharding(self.trainer, arr.ndim + 1)
+            ).compile().memory_analysis()
+            self._gather_bytes_cache[key] = (
+                mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+            )
+        return self._gather_bytes_cache[key]
+
+    def stage(self, arr):
+        """``arr`` on the device, under ``train.upload``.  One chip takes the
+        default device uncommitted, as the host gather's upload did, so the
+        epoch programs see the argument placement they always saw."""
+        with span(self.tracer, "train.upload"):
+            if self.trainer is None:
+                return jnp.asarray(arr)
+            return jax.device_put(arr, self._stage_sharding(arr))
 
     def hidden_epoch(self, li: int) -> Callable:
         run = self._hidden_cache.get(li)

@@ -16,11 +16,18 @@ control flow buys three things:
   prefix (:class:`repro.runtime.activations.ActivationStore`) and every
   epoch of the phase gathers from the cached level-k array instead of
   re-running the frozen stack per batch;
+* **stage-once input** — a host array a phase's epochs gather from is
+  placed on the device once at the phase's start (the plan's
+  :meth:`~repro.runtime.plans.ScanPlan.stage`, where the device has
+  room), so each shuffled epoch is gathered there rather than on the host
+  and shipped again;
 * **observability** — every history entry carries a ``seconds`` field
-  (epoch wall-time, blocked on the result) plus explicit ``project``
-  entries, so the phase-program speedup is measurable from the API; each
-  runs under a ``train.<phase>`` span (:func:`repro.runtime.trace.span`)
-  that a JAX profile shows beside the device's ops.
+  (epoch wall-time, blocked on the result) plus explicit ``project`` and
+  ``stage`` entries, and each epoch says where its input was gathered
+  (``input``: ``"device"`` or ``"host"``), so the phase-program speedup is
+  measurable from the API; each runs under a ``train.<phase>`` span
+  (:func:`repro.runtime.trace.span`) that a JAX profile shows beside the
+  device's ops.
 
 The driver is engine-agnostic: it calls the bound
 :class:`repro.runtime.plans.ExecutionPlan`'s cached epoch runners when the
@@ -243,18 +250,44 @@ def check_finite(net, tree, where: str) -> None:
 _check_finite = check_finite
 
 
-def _phase_input(net, level: int, states, x, batch_size, history):
-    """The training input for a phase starting at ``level``: the cached
-    level-k projection (project-once) or the raw dataset (fused path)."""
+def _phase_input(net, level: int, states, x, n, batch_size, history,
+                 stage: bool = True):
+    """The array a phase's epochs gather from, and whether it is the cached
+    level-k projection (project-once) rather than the raw dataset (level 0,
+    and the fused path).  The raw dataset is staged on the device once for
+    the phase where the plan stages it (``stage=False`` for a phase that
+    runs no epoch); a projection stays where the activation store put it,
+    on the device or, past the store's budget, on the host."""
     store = net.activations
-    if store is None:
-        return None
-    if level == 0:
-        return x
+    if store is None or level == 0:
+        if stage:
+            x = _stage(net, x, "x", n, batch_size, history)
+        return x, store is not None
     with _timed(net, history, {"phase": "project", "level": level}) as fence:
+        # The store keys on the caller's own x, never on a staged copy.
         xk = store.level(level, states, x, chunk=batch_size)
         fence(xk)
-    return xk
+    return xk, True
+
+
+def _stage(net, arr, name: str, n: int, batch_size: int, history):
+    """``arr`` placed on the device once for a phase's epochs, timed as a
+    ``stage`` history entry; ``arr`` itself where the plan keeps the host
+    gather (a device array, BatchPlan, or too little free device memory)."""
+    if not net.plan.stages(arr, n, batch_size):
+        return arr
+    entry = {"phase": "stage", "level": 0, "array": name,
+             "bytes": int(arr.nbytes)}
+    with _timed(net, history, entry) as fence:
+        staged = net.plan.stage(arr)
+        fence(staged)
+    return staged
+
+
+def _input(*arrays) -> str:
+    """Where an epoch gathers its stack: on the device when every array it
+    gathers from is a ``jax.Array``, else on the host."""
+    return "device" if all(isinstance(a, jax.Array) for a in arrays) else "host"
 
 
 def _run_hidden_phase(
@@ -264,16 +297,17 @@ def _run_hidden_phase(
     layer = net.hidden_layers[li]
     states = list(net.state.layers)
     state = net._donation_safe(net.plan.place_state(layer, states[li]))
-    xk = _phase_input(net, li, states, x, batch_size, history)
-    if xk is not None:
+    xk, cached = _phase_input(net, li, states, x, n, batch_size, history)
+    if cached:
         run_epoch = net.plan.hidden_epoch_cached(li)
         step = lambda st, idx: run_epoch(st, xk, idx, batch_size)  # noqa: E731
     else:
         run_epoch = net.plan.hidden_epoch(li)
         below = states[:li]
-        step = lambda st, idx: run_epoch(st, below, x, idx, batch_size)  # noqa: E731
+        step = lambda st, idx: run_epoch(st, below, xk, idx, batch_size)  # noqa: E731
+    where = _input(xk)
     for epoch in range(phase.epochs):
-        entry = {"phase": f"hidden{li}", "epoch": epoch}
+        entry = {"phase": f"hidden{li}", "epoch": epoch, "input": where}
         with _timed(net, history, entry) as fence:
             idx = net._epoch_indices(n, n_total, shuffle)
             state = step(state, idx)
@@ -300,18 +334,20 @@ def _run_bcpnn_phase(
     li = len(net.layers) - 1
     states = list(net.state.layers)
     state = net._donation_safe(net.plan.place_state(layer, states[li]))
-    hk = _phase_input(net, li, states, x, batch_size, history)
-    if hk is not None:
+    hk, cached = _phase_input(net, li, states, x, n, batch_size, history)
+    ys = _stage(net, y, "y", n, batch_size, history)
+    if cached:
         run_epoch = net.plan.readout_epoch_cached()
-        step = lambda st, idx: run_epoch(st, hk, y, idx, batch_size)  # noqa: E731
+        step = lambda st, idx: run_epoch(st, hk, ys, idx, batch_size)  # noqa: E731
     else:
         run_epoch = net.plan.readout_epoch()
         hidden_states = states[:li]
         step = lambda st, idx: run_epoch(  # noqa: E731
-            st, hidden_states, x, y, idx, batch_size
+            st, hidden_states, hk, ys, idx, batch_size
         )
+    where = _input(hk, ys)
     for epoch in range(phase.epochs):
-        entry = {"phase": "readout", "epoch": epoch}
+        entry = {"phase": "readout", "epoch": epoch, "input": where}
         with _timed(net, history, entry) as fence:
             idx = net._epoch_indices(n, n_total, shuffle)
             state = step(state, idx)
@@ -332,16 +368,20 @@ def _run_sgd_phase(
     params, opt_state, run_epoch = net._sgd_setup(y, phase.lr, phase.reset)
     states = list(net.state.layers)
     n_hidden = len(net.hidden_layers)
-    hk = _phase_input(net, n_hidden, states, x, batch_size, history)
-    if hk is not None:
-        step = lambda p, s, idx: run_epoch(p, s, hk, y, idx, batch_size)  # noqa: E731
+    # epochs=0 only initializes the head: nothing to gather, nothing to stage
+    hk, cached = _phase_input(net, n_hidden, states, x, n, batch_size,
+                              history, stage=phase.epochs > 0)
+    ys = _stage(net, y, "y", n, batch_size, history) if phase.epochs else y
+    if cached:
+        step = lambda p, s, idx: run_epoch(p, s, hk, ys, idx, batch_size)  # noqa: E731
     else:
         hidden_states = states[:n_hidden]
         step = lambda p, s, idx: run_epoch(  # noqa: E731
-            p, s, hidden_states, x, y, idx, batch_size
+            p, s, hidden_states, hk, ys, idx, batch_size
         )
+    where = _input(hk, ys)
     for epoch in range(phase.epochs):
-        entry = {"phase": "sgd_readout", "epoch": epoch}
+        entry = {"phase": "sgd_readout", "epoch": epoch, "input": where}
         with _timed(net, history, entry) as fence:
             idx = net._epoch_indices(n, n_total, shuffle)
             params, opt_state, loss = step(params, opt_state, idx)

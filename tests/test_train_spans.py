@@ -23,6 +23,7 @@ from repro.data import complementary_code, mnist_like  # noqa: E402
 from repro.runtime import TraceConfig  # noqa: E402
 
 EPOCH = ("train.gather", "train.upload", "train.dispatch", "train.fence")
+STAGE = ("train.upload", "train.fence")
 
 
 def _network(trace=None):
@@ -50,8 +51,12 @@ def test_profile_nests_the_epoch_spans(tmp_path):
     host = [ev for ev in devtrace.load(str(tmp_path))["host"]
             if ev[2].startswith("train.")]
     (fit,) = [ev for ev in host if ev[2] == "train.fit"]
+    (stage,) = [ev for ev in _inside(host, fit) if ev[2] == "train.stage"]
+    inner = [ev[2] for ev in _inside(host, stage) if ev is not stage]
+    assert inner == list(STAGE)
     epochs = [ev for ev in _inside(host, fit) if ev[2] == "train.hidden0"]
     assert len(epochs) == 2
+    assert all(stage[1] <= epoch[0] for epoch in epochs)
     for epoch in epochs:
         inner = [ev[2] for ev in _inside(host, epoch) if ev is not epoch]
         assert inner == list(EPOCH)
@@ -62,17 +67,19 @@ def test_tracer_ring_holds_the_same_spans():
     res = _fit(compiled, x)
     # recorded as each span closes, innermost first
     names = [s.name for s in compiled.tracer.spans()]
-    assert names == [*EPOCH, "train.hidden0"] * 2 + ["train.fit"]
+    assert names == [*STAGE, "train.stage",
+                     *[*EPOCH, "train.hidden0"] * 2, "train.fit"]
     assert {s.trace_id for s in compiled.tracer.spans()} == {0}
     hidden = compiled.tracer.spans("train.hidden0")
     assert [s.attrs["host_s"] for s in hidden] == [
-        h["host_s"] for h in res.history]
+        h["host_s"] for h in res.history if h["phase"] == "hidden0"]
 
 
 def test_traces_counts_compiles_per_epoch():
     compiled, x = _network(TraceConfig())
     res = _fit(compiled, x)
-    assert res.history[0]["traces"] >= 1 and res.history[1]["traces"] == 0
+    epochs = [h for h in res.history if h["phase"] == "hidden0"]
+    assert epochs[0]["traces"] >= 1 and epochs[1]["traces"] == 0
     dispatch = compiled.tracer.spans("train.dispatch")
     assert [s.attrs["traces"] for s in dispatch] == [1, 0]
     assert "hidden_epoch_cached[0]" in compiled.plan.jit_cache_sizes()
