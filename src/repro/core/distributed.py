@@ -55,13 +55,13 @@ def dp_learning_cycle(
     lam: float,
     k_b: float,
     axes: Sequence[str],
-    mask: Optional[jnp.ndarray] = None,
 ):
     """One learning cycle on a *local* sub-batch inside shard_map.
 
     Local batch means are pmean-ed over `axes` (the paper's allreduce of
     <a_i>, <a_j>, <a_i (x) a_j>), then the EWMA/weight update runs locally.
-    Equal shard sizes make mean-of-means == global mean exactly.
+    Equal shard sizes make mean-of-means == global mean exactly.  Returns
+    the unmasked w; the caller applies the receptive fields.
     """
     mi, mj, mij = learning.batch_means(ai, aj)
     mi = jax.lax.pmean(mi, axes)
@@ -69,8 +69,6 @@ def dp_learning_cycle(
     mij = jax.lax.pmean(mij, axes)
     new_state = learning.update_marginals(state, mi, mj, mij, lam)
     w, b = learning.weights_from_marginals(new_state, k_b)
-    if mask is not None:
-        w = w * mask
     return new_state, w, b
 
 
@@ -203,29 +201,27 @@ class DataParallelTrainer:
         x_spec = P(baxes, None)
 
         def local_step(state: LayerState, xb, yb=None):
-            mask = (
-                state.plast.unit_mask(spec.pre, _local_post(spec.post, state.w))
-                if state.plast is not None
-                else None
-            )
+            post_layout = _local_post(spec.post, state.w)
             # Forward on the local hidden shard; softmax is HCU-local so no
-            # collective is needed (HCUs never straddle shards).  The
-            # soft-WTA gain must scale the support exactly as
-            # learning.forward does — omitting it silently diverged
-            # shard_map training from the single-device and pjit paths for
-            # any gain != 1 layer (caught by the deep-network parity test).
-            s = xb @ (state.w * mask if mask is not None else state.w) + state.b
+            # collective is needed (HCUs never straddle shards).  `w` is
+            # already masked (LayerState).  The soft-WTA gain must scale the
+            # support exactly as learning.forward does — omitting it
+            # silently diverged shard_map training from the single-device
+            # and pjit paths for any gain != 1 layer (caught by the
+            # deep-network parity test).
+            s = xb @ state.w + state.b
             if spec.gain != 1.0:
                 s = s * spec.gain
-            post_layout = _local_post(spec.post, state.w)
             aj = learning.hcu_softmax(s, post_layout)
             if supervised:
                 aj = jax.nn.one_hot(yb, state.w.shape[1], dtype=xb.dtype)
             marg, w, b = state.marginals, state.w, state.b
             for _ in range(spec.n_cycles):
                 marg, w, b = dp_learning_cycle(
-                    marg, xb, aj, spec.lam, spec.k_b, baxes, mask=mask
+                    marg, xb, aj, spec.lam, spec.k_b, baxes
                 )
+                if state.plast is not None:
+                    w = state.plast.mask_weights(w, spec.pre, post_layout)
             return LayerState(marg, w, b, state.plast, state.step + 1)
 
         if supervised:

@@ -35,6 +35,11 @@ class LayerState(NamedTuple):
 
     w/b are *derived* from marginals each cycle but cached here because
     inference uses them without touching marginals.
+
+    Invariant: where `plast` is set, `w` is zero on every silent (input HCU,
+    hidden HCU) block.  Every writer of `w` masks it (`init`, the learning
+    cycle, the rewire, `ContinualPlan`'s merge), so the jnp forward reads `w`
+    alone.
     """
 
     marginals: MarginalState
@@ -108,38 +113,49 @@ def _state_format(spec: "BCPNNLayerSpec"):
     return None
 
 
+def _jnp_path(spec: BCPNNLayerSpec) -> bool:
+    """Neither the kernels nor a reduced-precision datapath."""
+    return not spec.use_kernels and _datapath_policy(spec) is None
+
+
+def _unit_mask(spec: BCPNNLayerSpec, state: LayerState) -> Optional[jnp.ndarray]:
+    """The F×H mask operand of the kernel and reduced-datapath paths."""
+    if state.plast is None:
+        return None
+    return state.plast.unit_mask(spec.pre, spec.post)
+
+
 def _forward(spec: BCPNNLayerSpec, state: LayerState, x: jnp.ndarray) -> jnp.ndarray:
-    """s = x @ (w o mask) + b; softmax per HCU. Kernel or reference path."""
-    mask = (
-        state.plast.unit_mask(spec.pre, spec.post)
-        if state.plast is not None
-        else None
-    )
+    """s = x @ w + b; softmax per HCU. Kernel or reference path.
+
+    `w` is already masked (LayerState); the kernel and reduced-datapath
+    paths still take the F×H mask as an operand."""
+    if _jnp_path(spec):
+        return learning.forward(x, state.w, state.b, spec.post, gain=spec.gain)
+    mask = _unit_mask(spec, state)
     if _datapath_policy(spec) is not None:
         from repro.precision.policy import quantized_forward
 
         return quantized_forward(
             x, state.w, state.b, spec.post, spec.precision, mask, gain=spec.gain
         )
-    if spec.use_kernels:
-        from repro.kernels import ops as kops
+    from repro.kernels import ops as kops
 
-        s = kops.masked_matmul(x, state.w, state.b, mask=mask)
-        if spec.gain != 1.0:
-            s = s * spec.gain
-        return kops.hcu_softmax(s, n_hcu=spec.post.n_hcu, n_mcu=spec.post.n_mcu)
-    return learning.forward(x, state.w, state.b, spec.post, mask=mask, gain=spec.gain)
+    s = kops.masked_matmul(x, state.w, state.b, mask=mask)
+    if spec.gain != 1.0:
+        s = s * spec.gain
+    return kops.hcu_softmax(s, n_hcu=spec.post.n_hcu, n_mcu=spec.post.n_mcu)
 
 
 def _learn(
     spec: BCPNNLayerSpec, state: LayerState, ai: jnp.ndarray, aj: jnp.ndarray
 ) -> LayerState:
-    """n_cycles of the EWMA marginal -> weight update (Alg.1 L10-16)."""
-    mask = (
-        state.plast.unit_mask(spec.pre, spec.post)
-        if state.plast is not None
-        else None
-    )
+    """n_cycles of the EWMA marginal -> weight update (Alg.1 L10-16).
+
+    The jnp path applies the receptive fields with `mask_weights`, in the
+    pass that writes w; the kernel and reduced-datapath paths take the F×H
+    mask."""
+    mask = None if _jnp_path(spec) else _unit_mask(spec, state)
 
     marg, w, b = state.marginals, state.w, state.b
     sfmt = _state_format(spec)
@@ -166,15 +182,13 @@ def _learn(
                     cj=marg.cj.astype(jnp.float32),
                     cij=marg.cij.astype(jnp.float32),
                 )
-            marg, w, b = learning.learning_cycle(
-                marg, ai, aj, spec.lam, spec.k_b, mask=mask
-            )
+            marg, w, b = learning.learning_cycle(marg, ai, aj, spec.lam, spec.k_b)
             if sfmt is not None:
                 from repro.precision.policy import state_quantized_cycle
 
-                marg, w, b = state_quantized_cycle(
-                    marg, spec.precision, k_b=spec.k_b, mask=mask
-                )
+                marg, w, b = state_quantized_cycle(marg, spec.precision, k_b=spec.k_b)
+            if state.plast is not None:
+                w = state.plast.mask_weights(w, spec.pre, spec.post)
     return LayerState(
         marginals=marg, w=w, b=b, plast=state.plast, step=state.step + 1
     )
@@ -188,14 +202,9 @@ def _fused_train_batch(
     single `bcpnn_phase` Pallas call."""
     from repro.kernels import ops as kops
 
-    mask = (
-        state.plast.unit_mask(spec.pre, spec.post)
-        if state.plast is not None
-        else None
-    )
     marg, w, b, aj = kops.bcpnn_phase(
         state.marginals, x, state.w, state.b, spec.post,
-        lam=spec.lam, k_b=spec.k_b, gain=spec.gain, mask=mask,
+        lam=spec.lam, k_b=spec.k_b, gain=spec.gain, mask=_unit_mask(spec, state),
         n_cycles=spec.n_cycles, state_format=_state_format(spec),
     )
     new_state = LayerState(
@@ -247,7 +256,7 @@ class StructuralPlasticityLayer:
         else:
             plast = plasticity.full_mask(self.spec.pre, self.spec.post)
         w, b = learning.weights_from_marginals(marg, self.spec.k_b)
-        w = w * plast.unit_mask(self.spec.pre, self.spec.post)
+        w = plast.mask_weights(w, self.spec.pre, self.spec.post)
         return LayerState(
             marginals=marg, w=w, b=b, plast=plast, step=jnp.zeros((), jnp.int32)
         )
@@ -270,16 +279,18 @@ class StructuralPlasticityLayer:
         if self.fan_in >= self.spec.pre.n_hcu:
             return state  # dense: nothing to rewire
 
-        def rewire(s: LayerState) -> LayerState:
-            new_plast = plasticity.update_mask(
+        def rewire(s: LayerState) -> PlasticityState:
+            return plasticity.update_mask(
                 s.plast, s.marginals, self.spec.pre, self.spec.post
             )
-            # Re-apply the (possibly changed) mask to the cached weights.
-            w = s.w * new_plast.unit_mask(self.spec.pre, self.spec.post)
-            return LayerState(s.marginals, w, s.b, new_plast, s.step)
 
         do = (state.step % self.mask_update_every) == 0
-        return jax.lax.cond(do, rewire, lambda s: s, state)
+        plast = jax.lax.cond(do, rewire, lambda s: s.plast, state)
+        # Re-apply the (possibly changed) mask to the cached weights.  Out of
+        # the branch, the product fuses into the forward's read of w; a
+        # branch that returned w would copy it on every batch.
+        w = plast.mask_weights(state.w, self.spec.pre, self.spec.post)
+        return state._replace(w=w, plast=plast)
 
 
 class DenseLayer:

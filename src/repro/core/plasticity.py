@@ -12,10 +12,12 @@ Every N_HCU batches each hidden HCU:
      fixed fan-in — "the total number of active incoming connections is
      fixed", Sec.2).
 
-The mask is materialized at unit granularity (n_pre_units, n_post_units) for
-element-wise application to w (Alg.1 L16), but stored/updated at HCU
-granularity (n_pre_hcu, n_post_hcu) — exactly the receptive-field semantics
-of [26].
+The mask is stored and updated at HCU granularity (n_pre_hcu, n_post_hcu) —
+exactly the receptive-field semantics of [26] — and applied to w (Alg.1 L16)
+by `PlasticityState.mask_weights`, whose unit-granular factor the compiler
+fuses into the pass that writes w.  `unit_mask` materializes the
+(n_pre_units, n_post_units) array for the kernel and reduced-precision paths,
+which take it as an operand.
 
 Everything is vmapped/argmax-based so it jits cleanly; the update runs
 infrequently (the paper notes it is "not the primary candidate for
@@ -41,6 +43,33 @@ class PlasticityState(NamedTuple):
         """Expand the HCU-granular mask to unit granularity for w."""
         m = jnp.repeat(self.hcu_mask, pre.n_mcu, axis=0)
         return jnp.repeat(m, post.n_mcu, axis=1)
+
+    def mask_weights(
+        self, w: jnp.ndarray, pre: UnitLayout, post: UnitLayout
+    ) -> jnp.ndarray:
+        """`w * unit_mask(pre, post)`, bit for bit, with no unit-granular
+        mask built.
+
+        Each input unit's row of receptive fields is packed into bits, 32
+        hidden HCUs a word, and each element of `w` reads its hidden HCU's
+        bit: broadcasts, shifts and selects that the compiler fuses into the
+        pass that reads or writes `w`.  `unit_mask`'s column repeat is
+        instead a relayout of a whole F×H array.  `w` may be a hidden shard
+        (whole HCUs) with this state's matching shard of the mask.
+        """
+        n_pre_hcu, n_post_hcu = self.hcu_mask.shape
+        n_words = -(-n_post_hcu // 32)
+        lane = jnp.arange(n_words * 32, dtype=jnp.uint32) % 32
+        active = jnp.pad(self.hcu_mask > 0.5, ((0, 0), (0, n_words * 32 - n_post_hcu)))
+        words = jnp.where(active, jnp.left_shift(jnp.uint32(1), lane), jnp.uint32(0))
+        words = words.reshape(n_pre_hcu, n_words, 32).sum(-1, dtype=jnp.uint32)
+        words = jnp.repeat(words, pre.n_mcu, axis=0)  # (n_pre_units, n_words)
+        col = jnp.arange(w.shape[1], dtype=jnp.uint32) // post.n_mcu
+        word = words[:, :1]
+        for i in range(1, n_words):
+            word = jnp.where(col // 32 == i, words[:, i : i + 1], word)
+        on = jnp.right_shift(word, col % 32) & jnp.uint32(1)
+        return w * on.astype(w.dtype)
 
 
 def init_random_mask(

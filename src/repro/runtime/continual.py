@@ -442,7 +442,7 @@ class ContinualPlan(BatchedPlan):
                 w, b = weights_from_marginals(merged, spec.k_b)
                 base = states[0]
                 if base.plast is not None:
-                    w = w * base.plast.unit_mask(spec.pre, spec.post)
+                    w = base.plast.mask_weights(w, spec.pre, spec.post)
                 return LayerState(merged, w, b, base.plast,
                                   base.step + step_inc)
 

@@ -211,6 +211,42 @@ class TestOnlineOfflineParity:
         assert svc.plan._adapters["default"].buf_x == []
 
 
+# ------------------------------------------------ masked weights invariant
+class TestMaskedWeights:
+    def test_adapter_updates_and_merges_keep_silent_blocks_zero(self):
+        """Adapter updates of the hidden layer (rewires included) and the
+        merges into the base leave w zero on every silent (input HCU,
+        hidden HCU) block: the jnp forward reads w unmasked."""
+        compiled, xs, ys = _fitted()
+        svc = compiled.serve(
+            ServiceConfig(continual=_cc(layer=0, update_batch=4, merge_every=3))
+        )
+        plan = svc.plan
+        spec = compiled.layers[0].spec
+        pre, post = spec.pre, spec.post
+
+        def assert_masked(state):
+            w = np.asarray(state.w).reshape(
+                pre.n_hcu, pre.n_mcu, post.n_hcu, post.n_mcu
+            ).transpose(0, 2, 1, 3)
+            silent = np.asarray(state.plast.hcu_mask) == 0.0
+            assert silent.any() and not w[silent].any()
+            assert w[~silent].any()
+
+        before = np.asarray(compiled.state.layers[0].plast.hcu_mask)
+        swapped = merged = 0
+        for k in range(48):
+            ack = plan.learn(Feedback(xs[k], int(ys[k])))
+            state = plan._adapters["default"].state
+            after = np.asarray(state.plast.hcu_mask)
+            swapped += bool((after != before).any())
+            before = after
+            merged += ack["merged"]
+            assert_masked(state)
+            assert_masked(compiled.state.layers[0])
+        assert swapped and merged
+
+
 # --------------------------------------------------------- tenant isolation
 class TestTenantIsolation:
     def test_one_tenant_learning_never_touches_another(self):

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import (
+    StructuralPlasticityLayer,
     UnitLayout,
     batch_means,
     complementary_layout,
@@ -142,3 +143,115 @@ class TestPlasticity:
         assert m.shape == (6, 4)
         np.testing.assert_array_equal(np.asarray(m[:3, :2]), 1.0)
         np.testing.assert_array_equal(np.asarray(m[:3, 2:]), 0.0)
+
+    @pytest.mark.parametrize(
+        "n_pre_hcu,n_pre_mcu,n_post_hcu,n_post_mcu,shard",
+        [
+            (6, 2, 3, 4, None),
+            (5, 3, 40, 2, None),  # two words of receptive-field bits
+            (4, 2, 70, 3, (10, 45)),  # a hidden shard of whole HCUs
+        ],
+    )
+    def test_mask_weights_equals_unit_mask_product(
+        self, n_pre_hcu, n_pre_mcu, n_post_hcu, n_post_mcu, shard
+    ):
+        """`mask_weights` is `w * unit_mask` to the bit, signed zeros too."""
+        pre, post = UnitLayout(n_pre_hcu, n_pre_mcu), UnitLayout(n_post_hcu, n_post_mcu)
+        rng = np.random.default_rng(n_post_hcu)
+        hcu = (rng.random((n_pre_hcu, n_post_hcu)) < 0.5).astype(np.float32)
+        st = plasticity.PlasticityState(hcu_mask=jnp.asarray(hcu))
+        w = jnp.asarray(rng.standard_normal((pre.n_units, post.n_units)), jnp.float32)
+        want = w * st.unit_mask(pre, post)
+        if shard is not None:
+            lo, hi = shard
+            cols = slice(lo * n_post_mcu, hi * n_post_mcu)
+            local = plasticity.PlasticityState(hcu_mask=st.hcu_mask[:, lo:hi])
+            got, want = local.mask_weights(w[:, cols], pre, post), want[:, cols]
+        else:
+            got = jax.jit(st.mask_weights, static_argnums=(1, 2))(w, pre, post)
+        np.testing.assert_array_equal(
+            np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32)
+        )
+
+
+def _plasticity_layer(pre, post):
+    return StructuralPlasticityLayer(
+        pre, post, fan_in=pre.n_hcu // 2, lam=0.2, mask_update_every=3,
+        init_jitter=1.0, gain=4.0,
+    )
+
+
+def _inputs(pre, batch, seed):
+    """Complementary-coded rows: each input HCU's two units sum to one."""
+    p = np.random.default_rng(seed).random((batch, pre.n_hcu)).astype(np.float32)
+    return jnp.asarray(np.stack([p, 1.0 - p], -1).reshape(batch, pre.n_units))
+
+
+def _blocks(state, pre, post):
+    """w as (input HCU, hidden HCU, input MCU, hidden MCU) blocks."""
+    w = np.asarray(state.w).reshape(pre.n_hcu, pre.n_mcu, post.n_hcu, post.n_mcu)
+    return w.transpose(0, 2, 1, 3)
+
+
+def _silent_blocks(state, pre, post):
+    """w's entries on silent (input HCU, hidden HCU) blocks, and on active."""
+    silent = np.asarray(state.plast.hcu_mask) == 0.0
+    blocks = _blocks(state, pre, post)
+    return blocks[silent], blocks[~silent]
+
+
+class TestMaskedWeightsInvariant:
+    """`w` is zero on every silent (input HCU, hidden HCU) block after each
+    writer: init, a learning cycle, and a rewire that swaps a connection."""
+
+    pre, post = UnitLayout(12, 2), UnitLayout(4, 5)
+
+    def _layer(self):
+        return _plasticity_layer(self.pre, self.post)
+
+    def test_after_init_and_learning_cycles(self):
+        layer = self._layer()
+        state = layer.init(jax.random.PRNGKey(0))
+        silent, active = _silent_blocks(state, self.pre, self.post)
+        assert silent.size and not silent.any() and active.any()
+        x = _inputs(self.pre, 8, seed=1)
+        step = jax.jit(layer.train_batch)
+        for _ in range(5):  # crosses the rewire at step 3
+            state, _ = step(state, x)
+            silent, active = _silent_blocks(state, self.pre, self.post)
+            assert not silent.any() and active.any()
+
+    def test_after_a_rewire_that_swaps(self):
+        layer = self._layer()
+        state = layer.init(jax.random.PRNGKey(0))
+        step = jax.jit(layer.train_batch)
+        rewire = jax.jit(layer.maybe_update_mask)
+        for i in range(60):
+            state, _ = step(state, _inputs(self.pre, 8, seed=i))
+            if int(state.step) % layer.mask_update_every:
+                continue
+            rewired = rewire(state)
+            old = np.asarray(state.plast.hcu_mask)
+            new = np.asarray(rewired.plast.hcu_mask)
+            if (old != new).any():
+                break
+        else:
+            pytest.fail("no rewire swapped a connection")
+        silenced, activated = (old > new), (new > old)
+
+        def blocks(s):
+            return _blocks(s, self.pre, self.post)
+
+        # The silenced block held weights and is now zero; the activated
+        # block was silent, so it is zero until the next learning cycle.
+        assert blocks(state)[silenced].any()
+        assert not blocks(rewired)[silenced].any()
+        assert not blocks(rewired)[activated].any()
+        assert not _silent_blocks(rewired, self.pre, self.post)[0].any()
+        # train_batch rewires the same way, then the learning cycle writes
+        # the activated block and keeps every silent block zero.
+        learned, _ = step(state, _inputs(self.pre, 8, seed=99))
+        np.testing.assert_array_equal(np.asarray(learned.plast.hcu_mask), new)
+        assert blocks(learned)[activated].any()
+        assert not blocks(learned)[silenced].any()
+        assert not _silent_blocks(learned, self.pre, self.post)[0].any()

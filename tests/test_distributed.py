@@ -5,6 +5,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -218,3 +220,100 @@ def test_scan_engine_data_parallel_matches_single_device():
                 )
             print(mode, "OK")
     """)
+
+
+# The parent formulation of a hidden batch: a unit-granular F×H mask built
+# from hcu_mask every batch, multiplied into w by the rewire, the forward and
+# the learning cycle.  The program keeps w masked instead and applies the
+# receptive fields at HCU granularity; the two must agree bit for bit.
+PARITY = """
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import StructuralPlasticityLayer, UnitLayout, learning, plasticity
+    from repro.core.distributed import DataParallelTrainer, dp_learning_cycle, _local_post
+    from repro.core.layers import LayerState
+    from repro.runtime import hidden_epoch_cached_fn
+    from repro.runtime.epoch_engine import epoch_sharding
+
+    MODE, MESH = {mode!r}, {mesh!r}
+    pre, post = UnitLayout(12, 2), UnitLayout(4, 6)
+    layer = StructuralPlasticityLayer(pre, post, fan_in=5, lam=0.2,
+                                      mask_update_every=3, init_jitter=1.0,
+                                      gain=4.0)
+    st0 = layer.init(jax.random.PRNGKey(0))
+    p = np.random.default_rng(0).random((10, 16, pre.n_hcu)).astype(np.float32)
+    xs = jnp.asarray(np.stack([p, 1 - p], -1).reshape(10, 16, pre.n_units))
+
+    def parent_rewire(s):
+        def rewire(s):
+            new = plasticity.update_mask(s.plast, s.marginals, pre, post)
+            return LayerState(s.marginals, s.w * new.unit_mask(pre, post),
+                              s.b, new, s.step)
+        do = (s.step % layer.mask_update_every) == 0
+        return jax.lax.cond(do, rewire, lambda s: s, s)
+
+    def parent_step(s, xb):
+        s = parent_rewire(s)
+        mask = s.plast.unit_mask(pre, post)
+        aj = learning.forward(xb, s.w, s.b, post, mask=mask, gain=4.0)
+        marg, w, b = learning.learning_cycle(s.marginals, xb, aj, 0.2, 1.0,
+                                             mask=mask)
+        return LayerState(marg, w, b, s.plast, s.step + 1)
+
+    if MODE == "one_device":
+        new_step, ref_step, state, xs_in = None, parent_step, st0, xs
+    else:
+        mesh = jax.make_mesh(MESH, ("data", "model"))
+        tr = DataParallelTrainer(mesh, mode=MODE)
+        spec = tr._state_spec(layer, tr._can_shard_hidden(layer))
+        sh = jax.tree_util.tree_map(lambda q: NamedSharding(tr.mesh, q), spec,
+                                    is_leaf=lambda q: isinstance(q, P))
+        new_step = tr.hidden_step(layer)
+        if MODE == "pjit":
+            ref_step = jax.jit(parent_step, in_shardings=(sh, tr.batch_sharding()),
+                               out_shardings=sh)
+        else:
+            def local(s, xb):
+                lpost = _local_post(post, s.w)
+                mask = s.plast.unit_mask(pre, lpost)
+                sup = xb @ (s.w * mask) + s.b
+                aj = learning.hcu_softmax(sup * 4.0, lpost)
+                marg, w, b = dp_learning_cycle(s.marginals, xb, aj, 0.2, 1.0,
+                                               tr.baxes)
+                return LayerState(marg, w * mask, b, s.plast, s.step + 1)
+            body = jax.shard_map(local, mesh=tr.mesh,
+                                 in_specs=(spec, P(tr.baxes, None)),
+                                 out_specs=spec, check_vma=False)
+            ref_step = lambda s, xb: body(jax.jit(parent_rewire)(s), xb)
+        state = tr.place_state(layer, st0)
+        xs_in = jax.device_put(xs, epoch_sharding(tr, 3))
+
+    run = lambda step: hidden_epoch_cached_fn(layer, step_fn=step,
+                                              donate=False)(state, xs_in)
+    got, want = run(new_step), run(ref_step)
+    assert int(got.step) == 10  # rewires at steps 0, 3, 6 and 9
+    assert (np.asarray(got.plast.hcu_mask) != np.asarray(st0.plast.hcu_mask)).any()
+    names = ("ci", "cj", "cij", "w", "b", "hcu_mask")
+    leaves = lambda s: (*s.marginals, s.w, s.b, s.plast.hcu_mask)
+    for name, a, b in zip(names, leaves(got), leaves(want)):
+        a, b = np.asarray(jax.device_get(a)), np.asarray(jax.device_get(b))
+        assert a.shape == b.shape, name
+        assert (a.view(np.uint32) == b.view(np.uint32)).all(), name
+    print(MODE, MESH, "bit-identical")
+"""
+
+
+@pytest.mark.parametrize(
+    "mode,mesh",
+    [
+        ("one_device", None),
+        ("shard_map", (4, 1)),
+        ("shard_map", (2, 2)),  # hidden axis sharded: local masks
+        ("pjit", (4, 1)),
+    ],
+)
+def test_hcu_masked_update_matches_unit_mask_formulation(mode, mesh):
+    """An epoch crossing rewires that swap connections: the masked-w path
+    equals the unit-mask formulation to the bit, on one device and under
+    both trainers on four forced CPU devices."""
+    run_with_devices(PARITY.format(mode=mode, mesh=mesh), n=4)

@@ -1,4 +1,5 @@
-"""Compile the training kernels and the jnp train step for one TPU v5e.
+"""Compile the training kernels, the jnp train step and the hidden epoch's
+scan for one TPU v5e.
 
 The ``v5e:2x2`` topology is described, not attached: the TPU compiler runs
 here and refuses what the chip would refuse (a block shape off the (8, 128)
@@ -11,6 +12,7 @@ one process may load the TPU library at a time, and pytest-xdist workers
 import every test file.
 """
 import os
+import re
 
 import pytest
 
@@ -22,12 +24,15 @@ from repro.kernels.bcpnn_phase import bcpnn_phase_fused
 from repro.kernels.bcpnn_update import bcpnn_update_fused
 from repro.kernels.hcu_softmax import hcu_softmax
 from repro.kernels.masked_matmul import masked_matmul
+from repro.runtime import hidden_epoch_cached_fn
 
 # name -> (B, F, n_hcu, n_mcu)
 WIDTHS = {
     "mnist": (256, 1568, 30, 100),
     "stl10": (128, 55296, 20, 150),
 }
+# Active input HCUs per hidden HCU in the benchmark's cells.
+FAN_IN = {"mnist": 78, "stl10": 1024}
 HBM_BYTES = 16e9  # one v5e chip
 
 
@@ -112,3 +117,72 @@ def test_jnp_train_batch_compiles_for_v5e_at_stl10_width(one_chip):
     compiled = jax.jit(layer.train_batch).lower(state, x).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _computations(hlo: str) -> dict:
+    """Optimized HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and " = " in line:
+            comps[name].append(line.strip())
+    return comps
+
+
+def _loop_outside_rewire(hlo: str) -> list:
+    """Instruction lines of the epoch scan's while body and of every branch
+    of its conditionals but the last: lax.cond's true branch, the rewire."""
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY (%[\w.\-]+) ", hlo, re.M).group(1)
+    bodies = [
+        m.group(1)
+        for line in comps[entry]
+        for m in [re.search(r" while\(.*body=(%[\w.\-]+)", line)]
+        if m
+    ]
+    assert len(bodies) == 1, bodies
+    lines = list(comps[bodies[0]])
+    for line in comps[bodies[0]]:
+        branches = re.search(r"branch_computations=\{([^}]*)\}", line)
+        if branches:
+            names = [b.strip() for b in branches.group(1).split(",")]
+            for b in names[:-1]:
+                lines += comps[b]
+    return lines
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_hidden_epoch_loop_builds_no_unit_mask(one_chip, width):
+    """Outside the rewire branch the epoch scan builds and moves no
+    unit-granular (F×H) array: the receptive fields reach w at HCU
+    granularity inside the update's fusion, and the forward reads w alone.
+    A broadcast, reshape, copy, transpose or gather of F×H elements is the
+    per-batch mask build (or a relayout of w) this guards against."""
+    B, F, n_hcu, n_mcu = WIDTHS[width]
+    layer = StructuralPlasticityLayer(
+        UnitLayout(F // 2, 2), UnitLayout(n_hcu, n_mcu),
+        fan_in=FAN_IN[width], lam=0.05, init_jitter=1.0, gain=4.0,
+    )
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0))),
+    )
+    xs = _shapes(one_chip)(4, B, F)
+    hlo = hidden_epoch_cached_fn(layer).lower(state, xs).compile().as_text()
+    unit_elems = F * n_hcu * n_mcu
+    moves = ("broadcast", "reshape", "copy", "copy-start", "transpose", "gather")
+    found = []
+    for line in _loop_outside_rewire(hlo):
+        m = re.match(r"(%[\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(", line)
+        if not m or m.group(3) not in moves:
+            continue
+        for dims in re.findall(r"[a-z]+\d*\[([\d,]+)\]", m.group(2)):
+            n = 1
+            for d in dims.split(","):
+                n *= int(d)
+            if n == unit_elems:
+                found.append(f"{m.group(1)} {m.group(3)} {m.group(2)[:60]}")
+    assert not found, found
