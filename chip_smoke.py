@@ -47,7 +47,7 @@ from repro.runtime import ServiceConfig  # noqa: E402
 
 N_CLASSES = 10
 LAM = 0.05
-GAIN = 4.0  # as benchmarks/bench_stl10.py builds it
+GAIN = 4.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,8 +62,8 @@ class Sizes:
 
 
 # The paper's STL-10 network (Sec. 4.3): 96x96x3 features, 20 HCUs x 150
-# MCUs; fan-in as benchmarks/bench_stl10.py.  The epoch budget is cut to a
-# smoke run: 16 batches of 128 per epoch.
+# MCUs, fan-in 1,024 input HCUs.  The epoch budget is cut to a smoke run: 16
+# batches of 128 per epoch.
 STL10 = Sizes(
     n_features=96 * 96 * 3, n_hcu=20, n_mcu=150, fan_in=1024,
     n_train=2048, n_test=256, batch=128,
@@ -219,7 +219,7 @@ def phase_b(sizes: Sizes, data, seed: int, reference: dict) -> dict:
     xs = jax.ShapeDtypeStruct(
         (n_batches, sizes.batch, 2 * sizes.n_features), np.float32
     )
-    epoch = net.plan.jitted["hidden_epoch_cached[0]"]
+    epoch = net.plan.jitted["hidden_epoch[0]"]
     hlo = epoch.lower(net.state.layers[0], xs).compile().as_text()
     return {"errors": errs, "epoch_hlo": hlo}
 

@@ -32,8 +32,7 @@ fit/partial_fit arguments compile into an ordered list of hidden/readout
 phases, and at each phase boundary the dataset is projected ONCE through
 the newly-frozen prefix and cached (:mod:`repro.runtime.activations`) so
 epochs never recompute the frozen stack — the paper's staged greedy
-training made explicit.  ``ExecutionConfig(cache_activations=False)``
-selects the fused path, kept bit-exact as the parity reference.
+training made explicit.
 
 The legacy ``Network.fit(engine=..., trainer=...)`` signature survives as a
 deprecated shim that compiles on the fly and copies learned state back;
@@ -64,8 +63,8 @@ def build_head(layers) -> Callable:
     optional SGD head is an *argument* (jit's trace cache handles the
     bcpnn<->sgd switch), and it was trained on the output of the FULL
     hidden stack, so only a trailing DenseLayer is skipped when it is
-    active — shared by :func:`build_forward` (fused full-stack predict)
-    and ``CompiledNetwork._head_fn`` (project-once predict) so the two
+    active — shared by :func:`build_forward` (full-stack predict) and
+    ``CompiledNetwork._head_fn`` (predict on projected codes) so the two
     surfaces cannot diverge.
     """
     n_hidden = len(layers) - 1 if isinstance(layers[-1], DenseLayer) else len(layers)
@@ -83,9 +82,9 @@ def build_head(layers) -> Callable:
 def build_forward(layers) -> Callable:
     """One jitted full-network forward ``(states, readout_params, xb)``.
 
-    Shared by CompiledNetwork's fused predict path, the legacy
-    Network.predict shim, and the serving BatchedPlan — a single definition
-    keeps the surfaces bit-identical.
+    Shared by CompiledNetwork's predict on a network with no hidden layer,
+    the legacy Network.predict shim, and the serving BatchedPlan — a single
+    definition keeps the surfaces bit-identical.
     """
     n_hidden = len(layers) - 1 if isinstance(layers[-1], DenseLayer) else len(layers)
     head = build_head(layers)
@@ -135,14 +134,11 @@ class ExecutionConfig:
                  the quantized state tier (state_format=) but not with a
                  reduced-precision *datapath* policy.
     donate:      donate scan carries/epoch buffers on accelerators.
-    cache_activations:    project-once training (default): at each phase
-                 boundary the dataset is projected once through the frozen
-                 prefix and cached (repro.runtime.activations), so epochs
-                 never recompute the frozen stack.  False selects the fused
-                 path — the bit-exact parity reference.
-    activation_budget_mb: device-memory budget for cached level-k
-                 activations; levels beyond it are spilled to host memory
-                 (epoch gathers fall back to the host path transparently).
+    activation_budget_mb: device-memory budget for the level-k activations
+                 the frozen prefix is projected to once a phase
+                 (repro.runtime.activations); levels beyond it are spilled
+                 to host memory (epoch gathers fall back to the host path
+                 transparently).
     strict:      runtime hot-path verification (repro.analysis.strict):
                  epoch dispatches run under jax.transfer_guard("disallow"),
                  a recompile sentinel asserts every jitted callable compiles
@@ -170,7 +166,6 @@ class ExecutionConfig:
     use_kernels: Optional[bool] = None
     fused_phase: bool = False
     donate: bool = True
-    cache_activations: bool = True
     activation_budget_mb: float = 512.0
     strict: bool = False
     trace: Any = None
@@ -278,7 +273,7 @@ class CompiledNetwork:
         )
         if self.config.trainer is not None:
             self.plan = self.config.trainer.decorate(self.plan)
-        # Project-once activation store (None on the fused parity path).
+        # Project-once activation store: each frozen level's projection.
         from repro.runtime.activations import store_for
 
         self.activations = store_for(
@@ -331,11 +326,10 @@ class CompiledNetwork:
         self._sentinel.watch_all(self.plan.jitted, prefix="plan.")
         self._sentinel.watch("forward", self._fwd)
         self._sentinel.watch("head", self._head)
-        if self.activations is not None:
-            for (j, k), fn in self.activations._proj_scan.items():
-                self._sentinel.watch(f"proj_scan[{j}->{k}]", fn)
-            for (j, k), fn in self.activations._proj_chunk.items():
-                self._sentinel.watch(f"proj_chunk[{j}->{k}]", fn)
+        for (j, k), fn in self.activations._proj_scan.items():
+            self._sentinel.watch(f"proj_scan[{j}->{k}]", fn)
+        for (j, k), fn in self.activations._proj_chunk.items():
+            self._sentinel.watch(f"proj_chunk[{j}->{k}]", fn)
         self._sentinel.check(where)
 
     def _forward_fn(self) -> Callable:
@@ -357,14 +351,14 @@ class CompiledNetwork:
     def predict(self, x, batch_size: int = 1024) -> jnp.ndarray:
         """Class scores for a batch of inputs (cached jit).
 
-        With the activation store enabled the hidden stack runs through the
-        SAME level-H projection training used — so repeated predict/evaluate
-        on one dataset (and predict right after fit on the train set) skip
-        the frozen stack entirely; only the readout head runs per call."""
+        The hidden stack runs through the SAME level-H projection training
+        used — so repeated predict/evaluate on one dataset (and predict
+        right after fit on the train set) skip the frozen stack entirely;
+        only the readout head runs per call."""
         from repro.analysis.strict import dispatch_guard
 
         outs = []
-        if self.activations is not None and self.hidden_layers:
+        if self.hidden_layers:
             n_hidden = len(self.hidden_layers)
             h = self.activations.level(
                 n_hidden, list(self.state.layers), x, chunk=batch_size
@@ -481,7 +475,7 @@ class CompiledNetwork:
     # The one training driver: fit and partial_fit both compile their
     # arguments into a TrainProgram (repro.runtime.program) and hand it to
     # the phase-program executor, which routes each phase through the bound
-    # plan's cached (project-once) or fused epoch runners.
+    # plan's epoch runners.
     def _run(
         self, dataset, epochs_hidden, epochs_readout, batch_size, readout,
         readout_lr, shuffle, verbose, history, reset_readout,
@@ -553,9 +547,8 @@ class CompiledNetwork:
     def _sgd_setup(self, y, lr: float, reset: bool):
         """Hybrid-readout machinery for one SgdReadoutPhase: (params,
         opt_state, epoch runner) — AdamW + cross-entropy on frozen hidden
-        reps, the paper's 97.5%+ MNIST configuration.  The runner matches
-        the compiled network's execution mode (cached level-H inputs when
-        the activation store is on, fused otherwise) and is cached across
+        reps, the paper's 97.5%+ MNIST configuration.  The runner takes the
+        level-H projection as its input and is cached across
         fit/partial_fit calls."""
         from repro.core.network import sgd_readout_setup
 
@@ -588,11 +581,7 @@ class CompiledNetwork:
                 self.network.seed, n_hidden, y, lr, n_classes=n_classes,
                 init_params=not resume,
             )
-            run_epoch = (
-                self.plan.sgd_epoch_cached(opt, loss_fn)
-                if self.activations is not None
-                else self.plan.sgd_epoch(opt, loss_fn)
-            )
+            run_epoch = self.plan.sgd_epoch(opt, loss_fn)
             self._sgd_cache[key] = (opt, loss_fn, run_epoch)
         else:
             opt, loss_fn, run_epoch = cached
@@ -691,8 +680,7 @@ class CompiledNetwork:
             # eagerly so the adoption itself releases their device/host
             # bytes (and a served evaluate() right after close() can never
             # race a stale entry).
-            if self.activations is not None:
-                self.activations.invalidate_above(li)
+            self.activations.invalidate_above(li)
 
         # The session's default factories already build exactly the cells we
         # want from `bound`; only the shared LRUs and adoption are injected.

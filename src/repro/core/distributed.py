@@ -24,6 +24,7 @@ flat MPI scheme cannot express.
 """
 from __future__ import annotations
 
+import math
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
@@ -131,13 +132,18 @@ class DataParallelTrainer:
     def batch_sharding(self) -> NamedSharding:
         return NamedSharding(self.mesh, P(self.baxes, None))
 
-    def cache_sharding(self, ndim: int = 2) -> NamedSharding:
-        """Placement for a cached ``(n_samples, ...)`` level-k activation
-        array (repro.runtime.activations): rows sharded over the batch mesh
-        axes, so project-once caches live distributed and the per-epoch
-        ``jnp.take`` gather + epoch_sharding placement never funnel the
-        whole level through one device."""
-        return NamedSharding(self.mesh, P(self.baxes, *(None,) * (ndim - 1)))
+    def rows_sharding(self, shape) -> NamedSharding:
+        """Where an ``(n_rows, ...)`` array that epochs gather from lives —
+        a staged dataset (``ScanPlan.stage``) or an activation-store level
+        (``store_for``): rows sharded over the batch mesh axes, one shard
+        uploaded to each chip, where ``n_rows`` divides over them; a whole
+        copy on every chip where it does not."""
+        n_shards = math.prod(self.mesh.shape[a] for a in self.baxes)
+        if shape[0] % n_shards:
+            return NamedSharding(self.mesh, P())
+        return NamedSharding(
+            self.mesh, P(self.baxes, *(None,) * (len(shape) - 1))
+        )
 
     def place_state(self, layer, state: LayerState) -> LayerState:
         """Device-put a layer state with the trainer's shardings."""

@@ -15,8 +15,7 @@ hierarchy:
                 both written back exactly once.
 
 Compared to the unfused jnp path this saves one full HBM round-trip of the
-(N_F x N_H) C_ij and w tensors per cycle — on the bcpnn_xl config that is the
-difference between memory-bound and MXU-bound (see EXPERIMENTS.md §Perf).
+(N_F x N_H) C_ij and w tensors per cycle.
 
 The c_i'/c_j' vector EWMAs and the bias also run *inside* the kernel now:
 each batch tile contributes its row-sum to the resident (1, F_tile)/(1,

@@ -151,7 +151,7 @@ def bcpnn_phase(
 def count_pallas_calls(fn, *args, **kwargs) -> int:
     """Number of ``pallas_call`` equations in ``fn``'s jaxpr, recursing into
     sub-jaxprs (jit/scan/cond bodies).  This is the per-batch kernel-dispatch
-    metric bench_kernels reports and tests assert on (fused phase == 1)."""
+    count tests assert on (fused phase == 1)."""
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     return _count_pallas(closed.jaxpr)
 

@@ -17,7 +17,7 @@ Three execution schemes (cfg.moe_impl):
   capacity buffers are exchanged with ``lax.all_to_all`` to the owning
   expert shard and back.  Collective volume ~ 2 x tokens x k/E_shards x d x
   capacity_factor — the production dispatch at pod scale (beyond-paper
-  optimization; see EXPERIMENTS.md §Perf).
+  optimization).
 
 All schemes share ``_dispatch_compute`` so they are numerically identical
 (up to token-drop tie-breaking) and are cross-validated in tests.

@@ -13,11 +13,8 @@ from repro.runtime.engine import AsyncEngine, EngineStopped, QueueFull
 from repro.runtime.epoch_engine import (
     epoch_sharding,
     gather_batch,
-    hidden_epoch_cached_fn,
     hidden_epoch_fn,
-    readout_epoch_cached_fn,
     readout_epoch_fn,
-    sgd_epoch_cached_fn,
     sgd_epoch_fn,
     stack_epoch,
 )
@@ -113,9 +110,8 @@ __all__ = [
     "TenantMetrics", "RouterMetrics", "format_latency_line",
     "Router", "RouterConfig", "RouterError", "RouterStopped", "TenantConfig",
     "TenantQueueFull", "DeadlineExceeded", "NoEngineAvailable",
-    "epoch_sharding", "gather_batch", "hidden_epoch_cached_fn",
-    "hidden_epoch_fn", "readout_epoch_cached_fn", "readout_epoch_fn",
-    "sgd_epoch_cached_fn", "sgd_epoch_fn", "stack_epoch",
+    "epoch_sharding", "gather_batch", "hidden_epoch_fn", "readout_epoch_fn",
+    "sgd_epoch_fn", "stack_epoch",
     "BatchPlan", "ExecutionPlan", "ScanPlan", "make_plan",
     "BcpnnReadoutPhase", "HiddenPhase", "SgdReadoutPhase",
     "TrainProgram", "compile_program", "run_program",

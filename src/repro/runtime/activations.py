@@ -1,18 +1,18 @@
 """Project-once activation store for phase-program training.
 
 The paper's training scheme is explicitly staged: greedy layer-by-layer
-Hebbian epochs, then a supervised readout on *frozen* representations.  The
-fused execution path recomputes the frozen stack below the training layer
-inside every scan body — a depth-D network pays O(D^2 * epochs) redundant
-frozen forwards and re-transfers the raw input every epoch even when the
-layer's true input is a much smaller hidden code.
+Hebbian epochs, then a supervised readout on *frozen* representations.
+Recomputing the frozen stack below the training layer inside every batch
+would cost a depth-D network O(D^2 * epochs) redundant frozen forwards and
+re-transfer the raw input every epoch even when the layer's true input is a
+much smaller hidden code.
 
 :class:`ActivationStore` exploits the staging instead: at each phase
 boundary the dataset is projected ONCE through the newly-frozen prefix with
 a single jitted batched ``lax.scan`` and the level-k representation is
 cached.  Epoch shuffles then gather rows from the cached level-k array
 (`jnp.take` on device), so the per-epoch scan bodies contain no frozen
-forward at all (the ``*_epoch_cached_fn`` builders in
+forward at all (the ``*_epoch_fn`` builders in
 :mod:`repro.runtime.epoch_engine`).
 
 Residency is governed by a byte budget (``ExecutionConfig(
@@ -20,9 +20,9 @@ activation_budget_mb=...)``): cached levels live on device until the budget
 is exceeded, then the least-recently-used level is spilled to host memory
 (the epoch gather transparently falls back to the host path).  Projection
 chunking uses the caller's batch size and pads the ragged tail to a full
-chunk, so every row is produced by a GEMM of exactly the shape the fused
-path would have used — this is what keeps the cached and fused paths
-bit-exact (asserted in ``tests/test_deep_networks.py``).
+chunk, so every row is produced by a GEMM of exactly the shape a training
+batch has — this is what keeps projecting once bit-exact with recomputing
+the frozen prefix every batch (asserted in ``tests/test_deep_networks.py``).
 
 Invalidation is by object identity: an entry records the exact
 ``LayerState`` objects (and the dataset array) it was projected from, and is
@@ -100,7 +100,7 @@ class ActivationStore:
             if host_budget_bytes is not None
             else 4 * self.budget_bytes
         )
-        self._place = place  # device placement hook (trainer cache_sharding)
+        self._place = place  # device placement hook (trainer rows_sharding)
         self._entries: Dict[Tuple[int, int], _Entry] = {}  # (id(x), level)
         self._proj_scan: Dict[Tuple[int, int], Callable] = {}
         self._proj_chunk: Dict[Tuple[int, int], Callable] = {}
@@ -203,7 +203,7 @@ class ActivationStore:
         Full chunks run as ONE jitted scan over a ``(n_full, chunk, F)``
         stack; the ragged tail is zero-padded to a full chunk and sliced, so
         every row sees the same GEMM shape as a training batch — the
-        bit-exactness contract with the fused path.
+        bit-exactness contract with recomputing the prefix per batch.
         """
         self.stats["projections"] += 1
         frozen = tuple(states[j:k])
@@ -309,15 +309,12 @@ class ActivationStore:
 
 
 def store_for(layers: Sequence[Any], config, trainer=None) -> "ActivationStore":
-    """Build the store an :class:`ExecutionConfig` asks for (None when the
-    fused path is selected).  With a DataParallelTrainer, device-resident
-    levels are placed row-sharded over the batch axes
-    (``trainer.cache_sharding``) so epoch gathers stay distributed."""
-    if not getattr(config, "cache_activations", True):
-        return None
+    """Build the store an :class:`ExecutionConfig` sizes.  With a
+    DataParallelTrainer, device-resident levels are placed by the trainer's
+    one row rule (``trainer.rows_sharding``), as a staged dataset is."""
     place = None
     if trainer is not None:
-        place = lambda a: jax.device_put(a, trainer.cache_sharding(a.ndim))  # noqa: E731
+        place = lambda a: jax.device_put(a, trainer.rows_sharding(a.shape))  # noqa: E731
     budget = int(float(config.activation_budget_mb) * (1 << 20))
     return ActivationStore(layers, budget_bytes=budget, place=place)
 

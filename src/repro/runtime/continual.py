@@ -531,9 +531,7 @@ class ContinualPlan(BatchedPlan):
             self.compiled.state = NetworkState(
                 tuple(layers), self.compiled.state.readout
             )
-        store = self.compiled.activations
-        if store is not None:
-            store.invalidate_above(self._li)
+        self.compiled.activations.invalidate_above(self._li)
 
     def _drift_step(self, tenant: Optional[str] = None,
                     trace_id: Optional[int] = None) -> bool:

@@ -14,12 +14,10 @@ module keeps the whole Alg. 1 inner loop resident on the device:
 * the ``*_epoch_fn`` builders wrap a per-batch transition into a single
   jitted, buffer-donated ``lax.scan`` over the leading batch axis — the
   hidden Hebbian phase, the BCPNN readout phase, and the SGD readout phase
-  each get a scan body;
-* the ``*_epoch_cached_fn`` builders are the project-once variants: their
-  inputs are pre-projected level-k representations from the
-  :class:`repro.runtime.activations.ActivationStore`, so the scan bodies
-  contain no frozen-stack forward at all (the fused builders stay as the
-  bit-exact parity reference).
+  each get a scan body.  Their inputs are the layer's own: the raw dataset
+  for the first hidden layer, and above it the level-k representation the
+  :class:`repro.runtime.activations.ActivationStore` projected once through
+  the frozen prefix, so no scan body runs a frozen-stack forward.
 
 Numerics are bit-identical to the per-batch loop modulo reduction order:
 the scan body runs exactly the per-batch transition (including the
@@ -136,7 +134,7 @@ def epoch_sharding(trainer, ndim: int) -> Optional[NamedSharding]:
 # shape, so the buffer could not be reused and jax would warn at every
 # compile.  Each inner function has a name of its own, so each program
 # compiles, caches and profiles as ``jit_<name>`` (``jit_hidden_epoch``,
-# ``jit_readout_epoch_cached``, ...).
+# ``jit_readout_epoch``, ``jit_sgd_epoch``).
 # --------------------------------------------------------------------------
 def _donate(enabled: bool, *argnums: int) -> dict:
     """donate_argnums kwargs, suppressed on CPU (donation unsupported there
@@ -148,8 +146,8 @@ def _donate(enabled: bool, *argnums: int) -> dict:
 
 def forward_stack(layers: Sequence[Any]) -> Callable:
     """``(states, xb) -> xb`` through a frozen layer stack — the ONE
-    frozen-forward loop, shared by the scan bodies here and by
-    BatchPlan's per-batch reference loop."""
+    frozen-forward loop, shared by the activation store's projection and
+    the continual tier's adapter input."""
     def fwd(states, xb):
         for layer, state in zip(layers, states):
             xb = layer.forward(state, xb)
@@ -159,26 +157,18 @@ def forward_stack(layers: Sequence[Any]) -> Callable:
 
 
 def hidden_epoch_fn(
-    layer,
-    below_layers: Sequence[Any],
-    step_fn: Optional[Callable] = None,
-    donate: bool = True,
+    layer, step_fn: Optional[Callable] = None, donate: bool = True
 ) -> Callable:
-    """Jitted ``(state, below_states, xs) -> state`` for one Hebbian epoch.
-
-    ``xs``: stacked input epoch ``(n_batches, B, F)``.  ``below_states`` are
-    the frozen lower hidden layers (passed as traced args, not baked-in
-    constants, so the compiled epoch is reusable).  ``step_fn`` overrides the
-    per-batch transition — e.g. a DataParallelTrainer.hidden_step.
-    """
-    below = forward_stack(below_layers)
+    """Jitted ``(state, xs) -> state``: one Hebbian epoch on the layer's
+    inputs stacked ``(n_batches, B, F_level)``.  ``step_fn`` overrides the
+    per-batch transition — e.g. a DataParallelTrainer.hidden_step."""
     step = step_fn if step_fn is not None else (
         lambda s, xb: layer.train_batch(s, xb)[0]
     )
 
-    def hidden_epoch(state, below_states, xs):
+    def hidden_epoch(state, xs):
         def body(carry, xb):
-            return step(carry, below(below_states, xb)), None
+            return step(carry, xb), None
 
         state, _ = jax.lax.scan(body, state, xs)
         return state
@@ -187,90 +177,16 @@ def hidden_epoch_fn(
 
 
 def readout_epoch_fn(
-    layer,
-    hidden_layers: Sequence[Any],
-    step_fn: Optional[Callable] = None,
-    donate: bool = True,
-) -> Callable:
-    """Jitted ``(state, hidden_states, xs, ys) -> state`` for one supervised
-    BCPNN-readout epoch (post-activations clamped to one-hot labels)."""
-    below = forward_stack(hidden_layers)
-    step = step_fn if step_fn is not None else (
-        lambda s, hb, yb: layer.train_batch(s, hb, yb)[0]
-    )
-
-    def readout_epoch(state, hidden_states, xs, ys):
-        def body(carry, batch):
-            xb, yb = batch
-            return step(carry, below(hidden_states, xb), yb), None
-
-        state, _ = jax.lax.scan(body, state, (xs, ys))
-        return state
-
-    return jax.jit(readout_epoch, **_donate(donate, 0))
-
-
-def sgd_epoch_fn(
-    opt, hidden_layers: Sequence[Any], loss_fn: Callable, donate: bool = True
-) -> Callable:
-    """Jitted ``(params, opt_state, hidden_states, xs, ys) ->
-    (params, opt_state, losses)`` for one hybrid-readout (AdamW) epoch."""
-    below = forward_stack(hidden_layers)
-
-    def sgd_epoch(params, opt_state, hidden_states, xs, ys):
-        def body(carry, batch):
-            p, s = carry
-            xb, yb = batch
-            hb = below(hidden_states, xb)
-            loss, g = jax.value_and_grad(loss_fn)(p, hb, yb)
-            updates, s = opt.update(g, s, p)
-            p = jax.tree_util.tree_map(lambda a, u: a + u, p, updates)
-            return (p, s), loss
-
-        (params, opt_state), losses = jax.lax.scan(
-            body, (params, opt_state), (xs, ys)
-        )
-        return params, opt_state, losses
-
-    return jax.jit(sgd_epoch, **_donate(donate, 0, 1))
-
-
-# --------------------------------------------------------------------------
-# Cached-input (project-once) variants.  ``xs`` is already the layer's own
-# input representation — gathered from the ActivationStore's cached level-k
-# array — so the scan bodies contain NO frozen-stack forward.  This is the
-# phase-program fast path; the fused builders above remain the parity
-# reference (ExecutionConfig(cache_activations=False)).
-# --------------------------------------------------------------------------
-def hidden_epoch_cached_fn(
-    layer, step_fn: Optional[Callable] = None, donate: bool = True
-) -> Callable:
-    """Jitted ``(state, xs) -> state``: one Hebbian epoch on pre-projected
-    inputs ``(n_batches, B, F_level)``."""
-    step = step_fn if step_fn is not None else (
-        lambda s, xb: layer.train_batch(s, xb)[0]
-    )
-
-    def hidden_epoch_cached(state, xs):
-        def body(carry, xb):
-            return step(carry, xb), None
-
-        state, _ = jax.lax.scan(body, state, xs)
-        return state
-
-    return jax.jit(hidden_epoch_cached, **_donate(donate, 0))
-
-
-def readout_epoch_cached_fn(
     layer, step_fn: Optional[Callable] = None, donate: bool = True
 ) -> Callable:
     """Jitted ``(state, hs, ys) -> state``: one supervised BCPNN-readout
-    epoch on pre-projected hidden codes."""
+    epoch (post-activations clamped to one-hot labels) on the projected
+    hidden codes."""
     step = step_fn if step_fn is not None else (
         lambda s, hb, yb: layer.train_batch(s, hb, yb)[0]
     )
 
-    def readout_epoch_cached(state, hs, ys):
+    def readout_epoch(state, hs, ys):
         def body(carry, batch):
             hb, yb = batch
             return step(carry, hb, yb), None
@@ -278,14 +194,14 @@ def readout_epoch_cached_fn(
         state, _ = jax.lax.scan(body, state, (hs, ys))
         return state
 
-    return jax.jit(readout_epoch_cached, **_donate(donate, 0))
+    return jax.jit(readout_epoch, **_donate(donate, 0))
 
 
-def sgd_epoch_cached_fn(opt, loss_fn: Callable, donate: bool = True) -> Callable:
+def sgd_epoch_fn(opt, loss_fn: Callable, donate: bool = True) -> Callable:
     """Jitted ``(params, opt_state, hs, ys) -> (params, opt_state, losses)``:
-    one hybrid-readout (AdamW) epoch on pre-projected hidden codes."""
+    one hybrid-readout (AdamW) epoch on the projected hidden codes."""
 
-    def sgd_epoch_cached(params, opt_state, hs, ys):
+    def sgd_epoch(params, opt_state, hs, ys):
         def body(carry, batch):
             p, s = carry
             hb, yb = batch
@@ -299,4 +215,4 @@ def sgd_epoch_cached_fn(opt, loss_fn: Callable, donate: bool = True) -> Callable
         )
         return params, opt_state, losses
 
-    return jax.jit(sgd_epoch_cached, **_donate(donate, 0, 1))
+    return jax.jit(sgd_epoch, **_donate(donate, 0, 1))

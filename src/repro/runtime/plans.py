@@ -19,17 +19,19 @@ shard_map/pjit step (the paper's MPI backend) without changing the driver.
 Both plans cache their jitted epoch/step callables, so repeated ``fit`` /
 ``partial_fit`` calls on one CompiledNetwork never rebuild or re-trace.
 
-Epoch-runner calling convention (host-side data in, new state out):
+Epoch-runner calling convention (the phase's input in, new state out):
 
-    hidden_epoch(li)(state, below_states, x, idx, batch_size) -> state
-    readout_epoch()(state, hidden_states, x, y, idx, batch_size) -> state
-    sgd_epoch(opt, loss_fn)(params, opt_state, hidden_states, x, y, idx,
-                            batch_size) -> (params, opt_state, last_loss)
+    hidden_epoch(li)(state, x, idx, batch_size) -> state
+    readout_epoch()(state, h, y, idx, batch_size) -> state
+    sgd_epoch(opt, loss_fn)(params, opt_state, h, y, idx, batch_size)
+        -> (params, opt_state, last_loss)
 
-``x``/``y`` are the full arrays a phase gathers from: the caller's host
-arrays, or the device copies :meth:`ScanPlan.stage` made of them once
-for the phase; ``idx`` is the (already length-trimmed) shuffled index vector
-for this epoch.
+``x``/``h``/``y`` are the full arrays a phase gathers from, each already the
+layer's own input: the raw dataset for the first hidden layer (the caller's
+host array, or the device copy :meth:`ScanPlan.stage` made of it once for
+the phase) and above it the level-k projection the activation store holds;
+``idx`` is the (already length-trimmed) shuffled index vector for this
+epoch.
 """
 from __future__ import annotations
 
@@ -38,19 +40,15 @@ from typing import Any, Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding
 
 from repro.analysis.strict import dispatch_guard
 from repro.runtime.epoch_engine import (
     _take_epoch,
     epoch_sharding,
-    forward_stack,
     gather_batch,
-    hidden_epoch_cached_fn,
     hidden_epoch_fn,
-    readout_epoch_cached_fn,
     readout_epoch_fn,
-    sgd_epoch_cached_fn,
     sgd_epoch_fn,
     stack_epoch,
 )
@@ -93,7 +91,6 @@ class ExecutionPlan:
         self._hidden_cache: dict = {}
         self._hidden_step_cache: dict = {}
         self._readout_cache: Optional[Callable] = None
-        self._readout_cached: Optional[Callable] = None
         self._gather_bytes_cache: dict = {}
         self._plastic_cls = StructuralPlasticityLayer
         self._dense_cls = DenseLayer
@@ -127,7 +124,6 @@ class ExecutionPlan:
             self._hidden_cache
             or self._hidden_step_cache
             or self._readout_cache
-            or self._readout_cached
         ):
             raise RuntimeError(
                 "cannot bind a trainer to a plan that already compiled steps"
@@ -164,9 +160,6 @@ class ExecutionPlan:
         return fn
 
     # ----------------------------------------------------------- interface
-    # Fused runners recompute the frozen stack inside the epoch (x is the
-    # RAW dataset); cached runners take the layer's own pre-projected input
-    # (a level-k array from the ActivationStore) — the phase-program path.
     def hidden_epoch(self, li: int) -> Callable:
         raise NotImplementedError
 
@@ -174,15 +167,6 @@ class ExecutionPlan:
         raise NotImplementedError
 
     def sgd_epoch(self, opt, loss_fn: Callable) -> Callable:
-        raise NotImplementedError
-
-    def hidden_epoch_cached(self, li: int) -> Callable:
-        raise NotImplementedError
-
-    def readout_epoch_cached(self) -> Callable:
-        raise NotImplementedError
-
-    def sgd_epoch_cached(self, opt, loss_fn: Callable) -> Callable:
         raise NotImplementedError
 
 
@@ -214,19 +198,12 @@ class ScanPlan(ExecutionPlan):
         return state
 
     def _stage_sharding(self, arr) -> Optional[NamedSharding]:
-        """Where a staged array lives.  One chip: the default device.  Under
-        a trainer: rows sharded over the batch axes (``trainer.cache_sharding``,
-        as the activation caches), whose upload is one shard a chip; a whole
-        copy on every chip where the rows do not divide, an upload of the
-        whole set to each chip."""
+        """Where a staged array lives: the default device on one chip, the
+        trainer's row placement (``trainer.rows_sharding``, as the
+        activation store's levels) under a trainer."""
         if self.trainer is None:
             return None
-        sharding = self.trainer.cache_sharding(arr.ndim)
-        try:
-            sharding.shard_shape(arr.shape)
-        except ValueError:
-            return NamedSharding(self.trainer.mesh, P())
-        return sharding
+        return self.trainer.rows_sharding(arr.shape)
 
     def stages(self, arr, n: int, batch_size: int) -> bool:
         """Host arrays are staged whole, so every epoch of the phase gathers
@@ -279,14 +256,12 @@ class ScanPlan(ExecutionPlan):
         if run is None:
             layer = self.hidden_layers[li]
             step = self.trainer.hidden_step(layer) if self.trainer else None
-            epoch_fn = hidden_epoch_fn(
-                layer, self.layers[:li], step_fn=step, donate=self.donate
-            )
+            epoch_fn = hidden_epoch_fn(layer, step_fn=step, donate=self.donate)
             self.jitted[f"hidden_epoch[{li}]"] = epoch_fn
 
-            def run(state, below_states, x, idx, batch_size):
+            def run(state, x, idx, batch_size):
                 xs = self._stack(x, idx, batch_size)
-                return self._dispatch(epoch_fn, state, below_states, xs)
+                return self._dispatch(epoch_fn, state, xs)
 
             self._hidden_cache[li] = run
         return run
@@ -294,78 +269,24 @@ class ScanPlan(ExecutionPlan):
     def readout_epoch(self) -> Callable:
         if self._readout_cache is None:
             layer = self.readout_layer
-            li = len(self.layers) - 1
             step = self.trainer.readout_step(layer) if self.trainer else None
-            epoch_fn = readout_epoch_fn(
-                layer, self.layers[:li], step_fn=step, donate=self.donate
-            )
+            epoch_fn = readout_epoch_fn(layer, step_fn=step, donate=self.donate)
             self.jitted["readout_epoch"] = epoch_fn
 
-            def run(state, hidden_states, x, y, idx, batch_size):
-                xs = self._stack(x, idx, batch_size)
+            def run(state, h, y, idx, batch_size):
+                hs = self._stack(h, idx, batch_size)
                 ys = self._stack(y, idx, batch_size)
-                return self._dispatch(epoch_fn, state, hidden_states, xs, ys)
+                return self._dispatch(epoch_fn, state, hs, ys)
 
             self._readout_cache = run
         return self._readout_cache
 
     def sgd_epoch(self, opt, loss_fn: Callable) -> Callable:
-        epoch_fn = sgd_epoch_fn(
-            opt, self.hidden_layers, loss_fn, donate=self.donate
-        )
+        epoch_fn = sgd_epoch_fn(opt, loss_fn, donate=self.donate)
         self.jitted["sgd_epoch"] = epoch_fn
 
-        def run(params, opt_state, hidden_states, x, y, idx, batch_size):
-            xs = self._stack(x, idx, batch_size)
-            ys = self._stack(y, idx, batch_size)
-            params, opt_state, losses = self._dispatch(
-                epoch_fn, params, opt_state, hidden_states, xs, ys
-            )
-            return params, opt_state, losses[-1]
-
-        return run
-
-    # ------------------------------------------------- project-once runners
-    def hidden_epoch_cached(self, li: int) -> Callable:
-        run = self._hidden_cache.get(("cached", li))
-        if run is None:
-            layer = self.hidden_layers[li]
-            step = self.trainer.hidden_step(layer) if self.trainer else None
-            epoch_fn = hidden_epoch_cached_fn(
-                layer, step_fn=step, donate=self.donate
-            )
-            self.jitted[f"hidden_epoch_cached[{li}]"] = epoch_fn
-
-            def run(state, xk, idx, batch_size):
-                xs = self._stack(xk, idx, batch_size)
-                return self._dispatch(epoch_fn, state, xs)
-
-            self._hidden_cache[("cached", li)] = run
-        return run
-
-    def readout_epoch_cached(self) -> Callable:
-        if self._readout_cached is None:
-            layer = self.readout_layer
-            step = self.trainer.readout_step(layer) if self.trainer else None
-            epoch_fn = readout_epoch_cached_fn(
-                layer, step_fn=step, donate=self.donate
-            )
-            self.jitted["readout_epoch_cached"] = epoch_fn
-
-            def run(state, hk, y, idx, batch_size):
-                hs = self._stack(hk, idx, batch_size)
-                ys = self._stack(y, idx, batch_size)
-                return self._dispatch(epoch_fn, state, hs, ys)
-
-            self._readout_cached = run
-        return self._readout_cached
-
-    def sgd_epoch_cached(self, opt, loss_fn: Callable) -> Callable:
-        epoch_fn = sgd_epoch_cached_fn(opt, loss_fn, donate=self.donate)
-        self.jitted["sgd_epoch_cached"] = epoch_fn
-
-        def run(params, opt_state, hk, y, idx, batch_size):
-            hs = self._stack(hk, idx, batch_size)
+        def run(params, opt_state, h, y, idx, batch_size):
+            hs = self._stack(h, idx, batch_size)
             ys = self._stack(y, idx, batch_size)
             params, opt_state, losses = self._dispatch(
                 epoch_fn, params, opt_state, hs, ys
@@ -382,24 +303,17 @@ class BatchPlan(ExecutionPlan):
 
     name = "batch"
 
-    def _below_fn(self, upto: int) -> Callable:
-        fn = jax.jit(forward_stack(self.layers[:upto]))
-        self.jitted[f"below[{upto}]"] = fn
-        return fn
-
     def hidden_epoch(self, li: int) -> Callable:
         run = self._hidden_cache.get(li)
         if run is None:
             step = self.hidden_step(li)
-            below = self._below_fn(li)
 
-            def run(state, below_states, x, idx, batch_size):
+            def run(state, x, idx, batch_size):
                 with dispatch_guard(self.strict):
                     for b in range(0, idx.shape[0], batch_size):
-                        xb = gather_batch(x, idx[b : b + batch_size])
-                        if below_states:
-                            xb = below(below_states, xb)
-                        state = step(state, xb)
+                        state = step(
+                            state, gather_batch(x, idx[b : b + batch_size])
+                        )
                 return state
 
             self._hidden_cache[li] = run
@@ -408,7 +322,6 @@ class BatchPlan(ExecutionPlan):
     def readout_epoch(self) -> Callable:
         if self._readout_cache is None:
             layer = self.readout_layer
-            li = len(self.layers) - 1
             if self.trainer is not None:
                 step = self.trainer.readout_step(layer)
             else:
@@ -416,22 +329,20 @@ class BatchPlan(ExecutionPlan):
                     lambda s, hb, yb, _l=layer: _l.train_batch(s, hb, yb)[0]
                 )
             self.jitted["readout_step"] = step
-            below = self._below_fn(li)
 
-            def run(state, hidden_states, x, y, idx, batch_size):
+            def run(state, h, y, idx, batch_size):
                 with dispatch_guard(self.strict):
                     for b in range(0, idx.shape[0], batch_size):
                         sel = idx[b : b + batch_size]
-                        hb = below(hidden_states, gather_batch(x, sel))
-                        state = step(state, hb, gather_batch(y, sel))
+                        state = step(
+                            state, gather_batch(h, sel), gather_batch(y, sel)
+                        )
                 return state
 
             self._readout_cache = run
         return self._readout_cache
 
     def sgd_epoch(self, opt, loss_fn: Callable) -> Callable:
-        below = self._below_fn(len(self.hidden_layers))
-
         @jax.jit
         def step(p, s, hb, yb):
             loss, g = jax.value_and_grad(loss_fn)(p, hb, yb)
@@ -441,80 +352,14 @@ class BatchPlan(ExecutionPlan):
 
         self.jitted["sgd_step"] = step
 
-        def run(params, opt_state, hidden_states, x, y, idx, batch_size):
-            loss = jnp.zeros(())
-            with dispatch_guard(self.strict):
-                for b in range(0, idx.shape[0], batch_size):
-                    sel = idx[b : b + batch_size]
-                    hb = below(hidden_states, gather_batch(x, sel))
-                    params, opt_state, loss = step(
-                        params, opt_state, hb, gather_batch(y, sel)
-                    )
-            return params, opt_state, loss
-
-        return run
-
-    # ------------------------------------------------- project-once runners
-    # The reference loop routes its per-batch gathers through the cached
-    # level-k array exactly like the scan plan routes its epoch stack — one
-    # gather per batch, no frozen forward.
-    def hidden_epoch_cached(self, li: int) -> Callable:
-        run = self._hidden_cache.get(("cached", li))
-        if run is None:
-            step = self.hidden_step(li)
-
-            def run(state, xk, idx, batch_size):
-                with dispatch_guard(self.strict):
-                    for b in range(0, idx.shape[0], batch_size):
-                        state = step(
-                            state, gather_batch(xk, idx[b : b + batch_size])
-                        )
-                return state
-
-            self._hidden_cache[("cached", li)] = run
-        return run
-
-    def readout_epoch_cached(self) -> Callable:
-        if self._readout_cached is None:
-            layer = self.readout_layer
-            if self.trainer is not None:
-                step = self.trainer.readout_step(layer)
-            else:
-                step = jax.jit(
-                    lambda s, hb, yb, _l=layer: _l.train_batch(s, hb, yb)[0]
-                )
-            self.jitted["readout_step_cached"] = step
-
-            def run(state, hk, y, idx, batch_size):
-                with dispatch_guard(self.strict):
-                    for b in range(0, idx.shape[0], batch_size):
-                        sel = idx[b : b + batch_size]
-                        state = step(
-                            state, gather_batch(hk, sel), gather_batch(y, sel)
-                        )
-                return state
-
-            self._readout_cached = run
-        return self._readout_cached
-
-    def sgd_epoch_cached(self, opt, loss_fn: Callable) -> Callable:
-        @jax.jit
-        def step(p, s, hb, yb):
-            loss, g = jax.value_and_grad(loss_fn)(p, hb, yb)
-            updates, s = opt.update(g, s, p)
-            p = jax.tree_util.tree_map(lambda a, u: a + u, p, updates)
-            return p, s, loss
-
-        self.jitted["sgd_step_cached"] = step
-
-        def run(params, opt_state, hk, y, idx, batch_size):
+        def run(params, opt_state, h, y, idx, batch_size):
             loss = jnp.zeros(())
             with dispatch_guard(self.strict):
                 for b in range(0, idx.shape[0], batch_size):
                     sel = idx[b : b + batch_size]
                     params, opt_state, loss = step(
                         params, opt_state,
-                        gather_batch(hk, sel), gather_batch(y, sel),
+                        gather_batch(h, sel), gather_batch(y, sel),
                     )
             return params, opt_state, loss
 

@@ -30,10 +30,10 @@ control flow buys three things:
   device's ops.
 
 The driver is engine-agnostic: it calls the bound
-:class:`repro.runtime.plans.ExecutionPlan`'s cached epoch runners when the
-compiled network owns an ActivationStore (``ExecutionConfig(
-cache_activations=True)``, the default) and the fused runners otherwise —
-the two paths are bit-exact (``tests/test_deep_networks.py``).
+:class:`repro.runtime.plans.ExecutionPlan`'s epoch runners with each
+phase's own input — the raw dataset for the first hidden layer, the
+store's projection above it — bit-exact with recomputing the frozen prefix
+every batch (``tests/test_deep_networks.py``).
 """
 from __future__ import annotations
 
@@ -252,22 +252,20 @@ _check_finite = check_finite
 
 def _phase_input(net, level: int, states, x, n, batch_size, history,
                  stage: bool = True):
-    """The array a phase's epochs gather from, and whether it is the cached
-    level-k projection (project-once) rather than the raw dataset (level 0,
-    and the fused path).  The raw dataset is staged on the device once for
-    the phase where the plan stages it (``stage=False`` for a phase that
-    runs no epoch); a projection stays where the activation store put it,
-    on the device or, past the store's budget, on the host."""
-    store = net.activations
-    if store is None or level == 0:
+    """The array a phase's epochs gather from: the raw dataset at level 0,
+    staged on the device once for the phase where the plan stages it
+    (``stage=False`` for a phase that runs no epoch), else the level-k
+    projection, which stays where the activation store put it — on the
+    device or, past the store's budget, on the host."""
+    if level == 0:
         if stage:
             x = _stage(net, x, "x", n, batch_size, history)
-        return x, store is not None
+        return x
     with _timed(net, history, {"phase": "project", "level": level}) as fence:
         # The store keys on the caller's own x, never on a staged copy.
-        xk = store.level(level, states, x, chunk=batch_size)
+        xk = net.activations.level(level, states, x, chunk=batch_size)
         fence(xk)
-    return xk, True
+    return xk
 
 
 def _stage(net, arr, name: str, n: int, batch_size: int, history):
@@ -297,20 +295,14 @@ def _run_hidden_phase(
     layer = net.hidden_layers[li]
     states = list(net.state.layers)
     state = net._donation_safe(net.plan.place_state(layer, states[li]))
-    xk, cached = _phase_input(net, li, states, x, n, batch_size, history)
-    if cached:
-        run_epoch = net.plan.hidden_epoch_cached(li)
-        step = lambda st, idx: run_epoch(st, xk, idx, batch_size)  # noqa: E731
-    else:
-        run_epoch = net.plan.hidden_epoch(li)
-        below = states[:li]
-        step = lambda st, idx: run_epoch(st, below, xk, idx, batch_size)  # noqa: E731
+    xk = _phase_input(net, li, states, x, n, batch_size, history)
+    run_epoch = net.plan.hidden_epoch(li)
     where = _input(xk)
     for epoch in range(phase.epochs):
         entry = {"phase": f"hidden{li}", "epoch": epoch, "input": where}
         with _timed(net, history, entry) as fence:
             idx = net._epoch_indices(n, n_total, shuffle)
-            state = step(state, idx)
+            state = run_epoch(state, xk, idx, batch_size)
             _check_finite(net, state, f"hidden layer {li}, epoch {epoch}")
             fence(state)
         if verbose:
@@ -334,23 +326,15 @@ def _run_bcpnn_phase(
     li = len(net.layers) - 1
     states = list(net.state.layers)
     state = net._donation_safe(net.plan.place_state(layer, states[li]))
-    hk, cached = _phase_input(net, li, states, x, n, batch_size, history)
+    hk = _phase_input(net, li, states, x, n, batch_size, history)
     ys = _stage(net, y, "y", n, batch_size, history)
-    if cached:
-        run_epoch = net.plan.readout_epoch_cached()
-        step = lambda st, idx: run_epoch(st, hk, ys, idx, batch_size)  # noqa: E731
-    else:
-        run_epoch = net.plan.readout_epoch()
-        hidden_states = states[:li]
-        step = lambda st, idx: run_epoch(  # noqa: E731
-            st, hidden_states, hk, ys, idx, batch_size
-        )
+    run_epoch = net.plan.readout_epoch()
     where = _input(hk, ys)
     for epoch in range(phase.epochs):
         entry = {"phase": "readout", "epoch": epoch, "input": where}
         with _timed(net, history, entry) as fence:
             idx = net._epoch_indices(n, n_total, shuffle)
-            state = step(state, idx)
+            state = run_epoch(state, hk, ys, idx, batch_size)
             _check_finite(net, state, f"bcpnn readout epoch {epoch}")
             fence(state)
         if verbose:
@@ -369,22 +353,17 @@ def _run_sgd_phase(
     states = list(net.state.layers)
     n_hidden = len(net.hidden_layers)
     # epochs=0 only initializes the head: nothing to gather, nothing to stage
-    hk, cached = _phase_input(net, n_hidden, states, x, n, batch_size,
-                              history, stage=phase.epochs > 0)
+    hk = _phase_input(net, n_hidden, states, x, n, batch_size, history,
+                      stage=phase.epochs > 0)
     ys = _stage(net, y, "y", n, batch_size, history) if phase.epochs else y
-    if cached:
-        step = lambda p, s, idx: run_epoch(p, s, hk, ys, idx, batch_size)  # noqa: E731
-    else:
-        hidden_states = states[:n_hidden]
-        step = lambda p, s, idx: run_epoch(  # noqa: E731
-            p, s, hidden_states, hk, ys, idx, batch_size
-        )
     where = _input(hk, ys)
     for epoch in range(phase.epochs):
         entry = {"phase": "sgd_readout", "epoch": epoch, "input": where}
         with _timed(net, history, entry) as fence:
             idx = net._epoch_indices(n, n_total, shuffle)
-            params, opt_state, loss = step(params, opt_state, idx)
+            params, opt_state, loss = run_epoch(
+                params, opt_state, hk, ys, idx, batch_size
+            )
             _check_finite(net, params, f"sgd readout epoch {epoch}")
             fence(params)
         if verbose:

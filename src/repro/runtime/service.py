@@ -17,10 +17,9 @@ Three strategies, analogous to ScanPlan/BatchPlan on the training side:
   *shared* jitted level-H projection and readout head (the same
   ``build_head`` definition ``compiled.predict`` uses), with padding-bucket
   selection on the batch axis so a service facing arbitrary request sizes
-  compiles a bounded number of shapes.  With the activation store enabled,
-  repeated request batches hit the cached projection (content-addressed
-  canonicalization) and pay only the head; the fused full-stack forward
-  survives as the ``cache_activations=False`` fallback.  Zero-padding rows
+  compiles a bounded number of shapes.  Repeated request batches hit the
+  activation store's cached projection (content-addressed
+  canonicalization) and pay only the head.  Zero-padding rows
   never change real outputs (the forward is row-independent;
   property-tested).
 * :class:`DecodePlan` — prefill + continuous slot-batched decode for the LM
@@ -364,15 +363,13 @@ class BatchedPlan(ServePlan):
 
     ``predict`` chunks the input along the batch axis (chunk cap =
     ``max_batch`` or the largest bucket), pads each chunk up to its bucket
-    with zero rows, and — when the compiled network's activation store is
-    on — projects it through the SAME jitted frozen-stack projection
-    ``compiled.predict``/``evaluate`` use, then applies the ONE shared
-    ``build_head`` definition.  Padded chunks are content-canonicalized
-    (a small LRU maps chunk bytes -> one anchor array), so repeated
-    request batches hit the store's cached level-H projection and pay only
-    the readout head.  Without the store (``cache_activations=False``) the
-    fused full-network forward runs instead — identical outputs either
-    way, bounded trace count."""
+    with zero rows, projects it through the SAME jitted frozen-stack
+    projection ``compiled.predict``/``evaluate`` use, then applies the ONE
+    shared ``build_head`` definition.  Padded chunks are
+    content-canonicalized (a small LRU maps chunk bytes -> one anchor
+    array), so repeated request batches hit the store's cached level-H
+    projection and pay only the readout head.  A network with no hidden
+    layer runs the shared full-network forward instead."""
 
     name = "batched"
 
@@ -382,7 +379,7 @@ class BatchedPlan(ServePlan):
                  metrics: Optional[ServiceMetrics] = None):
         super().__init__(config, metrics)
         self.compiled = compiled
-        self._fwd = compiled._forward_fn()  # shared forward (fused fallback)
+        self._fwd = compiled._forward_fn()  # shared forward (no hidden layer)
         self._requests = 0
         self._rows = 0
         self._padded_rows = 0
@@ -426,18 +423,17 @@ class BatchedPlan(ServePlan):
         if self.compiled._head is not None:
             reg["head"] = self.compiled._head
         store = self.compiled.activations
-        if store is not None:
-            for (j, k), fn in store._proj_scan.items():
-                reg[f"proj_scan[{j}->{k}]"] = fn
-            for (j, k), fn in store._proj_chunk.items():
-                reg[f"proj_chunk[{j}->{k}]"] = fn
+        for (j, k), fn in store._proj_scan.items():
+            reg[f"proj_scan[{j}->{k}]"] = fn
+        for (j, k), fn in store._proj_chunk.items():
+            reg[f"proj_chunk[{j}->{k}]"] = fn
         return reg
 
     def _scores(self, xb: np.ndarray) -> jnp.ndarray:
         """One padded chunk -> class scores, through the shared head."""
         compiled = self.compiled
         state = compiled.state
-        if compiled.activations is not None and compiled.hidden_layers:
+        if compiled.hidden_layers:
             xb = self._canonical(xb)
             n_hidden = len(compiled.hidden_layers)
             h = compiled.activations.level(

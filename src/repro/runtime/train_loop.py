@@ -111,9 +111,12 @@ def train_loop(
         except Exception:
             restarts += 1
             fail_counts[step] = fail_counts.get(step, 0) + 1
+            if ckpt:
+                # A write still in flight may be replacing the very
+                # checkpoint the restore below would read (a replayed step
+                # saves its step again).
+                ckpt.wait()
             if fail_counts[step] > cfg.max_retries or not cfg.ckpt_dir:
-                if ckpt:
-                    ckpt.wait()
                 raise
             latest = latest_checkpoint(cfg.ckpt_dir)
             if latest is not None:

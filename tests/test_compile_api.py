@@ -162,9 +162,8 @@ class TestCompileTimeBinding:
 
 class TestPredictCache:
     def test_forward_built_once(self, dataset):
-        """predict's jitted callables are built once per compile — the
-        level-H head on the project-once path, the full forward on the
-        fused path — and never rebuilt across calls."""
+        """predict's jitted level-H head is built once per compile and
+        never rebuilt across calls."""
         ds, x, x_te, layout = dataset
         compiled = _build(layout).compile(ExecutionConfig())
         compiled.fit((x, ds.y_train), **KW)
@@ -174,15 +173,6 @@ class TestPredictCache:
         compiled.predict(x_te[:64])
         compiled.evaluate((x_te, ds.y_test))
         assert compiled._head is head  # no rebuild across calls
-
-        fused = _build(layout).compile(ExecutionConfig(cache_activations=False))
-        fused.fit((x, ds.y_train), **KW)
-        fused.predict(x_te[:32])
-        fwd = fused._fwd
-        assert fwd is not None
-        fused.predict(x_te[:64])
-        fused.evaluate((x_te, ds.y_test))
-        assert fused._fwd is fwd  # no rebuild across calls
 
     def test_sgd_head_on_headless_network(self, dataset):
         """A network with no DenseLayer readout + SGD head: the head was

@@ -2,12 +2,15 @@
 
 Tier-1 previously had ZERO multi-hidden-layer coverage; this suite pins the
 project-once pipeline on the configuration it was built for: scan-vs-batch
-parity, cached-vs-fused bit-exactness, per-layer epoch schedules, history
+parity, bit-exactness with a greedy reference that recomputes the frozen
+prefix every batch, per-layer epoch schedules, history
 wall-times, activation-store residency/invalidation, distributed
 (shard_map) parity, and a whole-network checkpoint round-trip.
 """
 import tempfile
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,7 +22,10 @@ from repro.core import (
     UnitLayout,
     onehot_layout,
 )
+from repro.core.compiled import build_forward
+from repro.core.network import sgd_readout_setup
 from repro.data import complementary_code, mnist_like
+from repro.runtime.epoch_engine import forward_stack
 
 H1, H2, H3 = UnitLayout(4, 4), UnitLayout(3, 4), UnitLayout(2, 4)
 
@@ -67,46 +73,116 @@ def assert_states_equal(states_a, states_b, exact=True):
         assert int(sa.step) == int(sb.step)
 
 
+def greedy_reference(compiled, x, y, epochs_hidden, epochs_readout, readout,
+                     rng, shuffle=True, batch_size=64, states=None):
+    """Alg. 1's greedy schedule written out, with no activation store: each
+    hidden layer trains on batches forwarded through the frozen layers
+    below it (``forward_stack``) inside every batch, then the readout on
+    batches forwarded through the whole hidden stack — one jitted scan an
+    epoch, over the batches ``compiled`` draws (``rng``).  Starts from
+    ``compiled``'s untrained states (or ``states``); returns the trained
+    layer states and the SGD head (None for the BCPNN readout)."""
+    layers = compiled.layers
+    states = list(compiled.state.layers if states is None else states)
+    n_hidden = len(compiled.hidden_layers)
+    n = x.shape[0] // batch_size * batch_size
+
+    def batches():
+        idx = rng.permutation(x.shape[0])[:n] if shuffle else np.arange(n)
+        idx = idx.reshape(-1, batch_size)
+        return jnp.asarray(x[idx]), jnp.asarray(y[idx])
+
+    for li in range(n_hidden):
+        below = forward_stack(layers[:li])
+
+        @jax.jit
+        def hidden_epoch(state, frozen, xs, _layer=layers[li], _below=below):
+            def body(s, xb):
+                return _layer.train_batch(s, _below(frozen, xb))[0], None
+
+            return jax.lax.scan(body, state, xs)[0]
+
+        for _ in range(epochs_hidden):
+            states[li] = hidden_epoch(states[li], states[:li], batches()[0])
+
+    below = forward_stack(layers[:n_hidden])
+    if readout == "bcpnn":
+        @jax.jit
+        def readout_epoch(state, frozen, xs, ys, _layer=layers[-1]):
+            def body(s, batch):
+                xb, yb = batch
+                return _layer.train_batch(s, below(frozen, xb), yb)[0], None
+
+            return jax.lax.scan(body, state, (xs, ys))[0]
+
+        for _ in range(epochs_readout):
+            states[-1] = readout_epoch(states[-1], states[:n_hidden],
+                                       *batches())
+        return states, None
+
+    params, opt, opt_state, loss_fn = sgd_readout_setup(
+        compiled.network.seed, layers[n_hidden - 1].spec.n_post, y, 1e-3,
+        n_classes=layers[-1].spec.n_post,
+    )
+
+    @jax.jit
+    def sgd_epoch(carry, frozen, xs, ys):
+        def body(carry, batch):
+            p, s = carry
+            xb, yb = batch
+            _, g = jax.value_and_grad(loss_fn)(p, below(frozen, xb), yb)
+            updates, s = opt.update(g, s, p)
+            return (jax.tree_util.tree_map(lambda a, u: a + u, p, updates),
+                    s), None
+
+        return jax.lax.scan(body, carry, (xs, ys))[0]
+
+    carry = (params, opt_state)
+    for _ in range(epochs_readout):
+        carry = sgd_epoch(carry, states[:n_hidden], *batches())
+    return states, carry[0]
+
+
 class TestCachedFusedBitExact:
-    """The project-once path must be bit-identical to the fused reference
-    (the acceptance contract of the activation store)."""
+    """Projecting the dataset once through each frozen prefix must be
+    bit-identical to recomputing the prefix every batch (the acceptance
+    contract of the activation store), checked against
+    :func:`greedy_reference`."""
 
     @pytest.mark.parametrize("readout", ["bcpnn", "sgd"])
     def test_fit_bitexact(self, dataset, readout):
         ds, x, x_te, layout = dataset
-        cached = build_deep(layout).compile(ExecutionConfig())
-        fused = build_deep(layout).compile(
-            ExecutionConfig(cache_activations=False)
+        compiled = build_deep(layout).compile(ExecutionConfig())
+        ref = build_deep(layout).compile(ExecutionConfig())
+        compiled.fit((x, ds.y_train), readout=readout, **KW)
+        states, head = greedy_reference(
+            ref, x, ds.y_train, KW["epochs_hidden"], KW["epochs_readout"],
+            readout, np.random.default_rng(ref.network.seed),
         )
-        cached.fit((x, ds.y_train), readout=readout, **KW)
-        fused.fit((x, ds.y_train), readout=readout, **KW)
-        assert_states_equal(cached.state.layers, fused.state.layers)
+        assert_states_equal(compiled.state.layers, states)
         if readout == "sgd":
             np.testing.assert_array_equal(
-                np.asarray(cached.state.readout["w"]),
-                np.asarray(fused.state.readout["w"]),
+                np.asarray(compiled.state.readout["w"]), np.asarray(head["w"])
             )
+        scores = build_forward(ref.layers)(states, head, jnp.asarray(x_te))
         np.testing.assert_array_equal(
-            np.asarray(cached.predict(x_te)), np.asarray(fused.predict(x_te))
+            np.asarray(compiled.predict(x_te)), np.asarray(scores)
         )
-        assert cached.evaluate((x_te, ds.y_test)) == fused.evaluate(
-            (x_te, ds.y_test)
-        )
+        accuracy = float(np.mean(np.argmax(scores, -1) == ds.y_test))
+        assert compiled.evaluate((x_te, ds.y_test)) == accuracy
 
     def test_partial_fit_bitexact(self, dataset):
         ds, x, _, layout = dataset
-        cached = build_deep(layout).compile(ExecutionConfig())
-        fused = build_deep(layout).compile(
-            ExecutionConfig(cache_activations=False)
-        )
-        for net in (cached, fused):
-            for i in (0, 128):
-                net.partial_fit(
-                    (x[i : i + 128], ds.y_train[i : i + 128]), batch_size=64,
-                    readout="bcpnn",
-                )
-        assert_states_equal(cached.state.layers, fused.state.layers)
-        assert int(cached.state.layers[0].step) == 4  # 2 chunks x 2 batches
+        compiled = build_deep(layout).compile(ExecutionConfig())
+        ref = build_deep(layout).compile(ExecutionConfig())
+        states = None
+        for i in (0, 128):
+            chunk = (x[i : i + 128], ds.y_train[i : i + 128])
+            compiled.partial_fit(chunk, batch_size=64, readout="bcpnn")
+            states, _ = greedy_reference(ref, *chunk, 1, 1, "bcpnn", None,
+                                         shuffle=False, states=states)
+        assert_states_equal(compiled.state.layers, states)
+        assert int(compiled.state.layers[0].step) == 4  # 2 chunks x 2 batches
 
     def test_host_spill_bitexact(self, dataset):
         """A ~0 activation budget forces every cached level to host memory;
@@ -316,8 +392,8 @@ class TestDeepServing:
 
 
 def test_deep_trainer_shard_map_parity():
-    """Data-parallel (shard_map) deep training == single-device, cached and
-    fused, on 4 fake devices (subprocess: jax locks the device count)."""
+    """Data-parallel (shard_map) deep training == single-device on 4 fake
+    devices (subprocess: jax locks the device count)."""
     from tests.test_distributed import run_with_devices
 
     run_with_devices("""
@@ -350,18 +426,16 @@ def test_deep_trainer_shard_map_parity():
         ref.fit((x, ds.y_train), **kw)
 
         mesh = jax.make_mesh((4,), ("data",))
-        for cache in (True, False):
-            tr = DataParallelTrainer(mesh, mode="shard_map")
-            dp = build(layout).compile(
-                ExecutionConfig(trainer=tr, cache_activations=cache))
-            dp.fit((x, ds.y_train), **kw)
-            for sa, sb in zip(dp.state.layers, ref.state.layers):
-                np.testing.assert_allclose(
-                    np.asarray(jax.device_get(sa.w)), np.asarray(sb.w),
-                    rtol=2e-4, atol=2e-5)
-                np.testing.assert_allclose(
-                    np.asarray(jax.device_get(sa.marginals.cij)),
-                    np.asarray(sb.marginals.cij), rtol=2e-4, atol=1e-7)
-                assert int(sa.step) == int(sb.step)
-            print("cache_activations=", cache, "OK")
+        tr = DataParallelTrainer(mesh, mode="shard_map")
+        dp = build(layout).compile(ExecutionConfig(trainer=tr))
+        dp.fit((x, ds.y_train), **kw)
+        for sa, sb in zip(dp.state.layers, ref.state.layers):
+            np.testing.assert_allclose(
+                np.asarray(jax.device_get(sa.w)), np.asarray(sb.w),
+                rtol=2e-4, atol=2e-5)
+            np.testing.assert_allclose(
+                np.asarray(jax.device_get(sa.marginals.cij)),
+                np.asarray(sb.marginals.cij), rtol=2e-4, atol=1e-7)
+            assert int(sa.step) == int(sb.step)
+        print("OK")
     """, n=4)

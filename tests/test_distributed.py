@@ -232,7 +232,7 @@ PARITY = """
     from repro.core import StructuralPlasticityLayer, UnitLayout, learning, plasticity
     from repro.core.distributed import DataParallelTrainer, dp_learning_cycle, _local_post
     from repro.core.layers import LayerState
-    from repro.runtime import hidden_epoch_cached_fn
+    from repro.runtime import hidden_epoch_fn
     from repro.runtime.epoch_engine import epoch_sharding
 
     MODE, MESH = {mode!r}, {mesh!r}
@@ -288,8 +288,8 @@ PARITY = """
         state = tr.place_state(layer, st0)
         xs_in = jax.device_put(xs, epoch_sharding(tr, 3))
 
-    run = lambda step: hidden_epoch_cached_fn(layer, step_fn=step,
-                                              donate=False)(state, xs_in)
+    run = lambda step: hidden_epoch_fn(layer, step_fn=step,
+                                       donate=False)(state, xs_in)
     got, want = run(new_step), run(ref_step)
     assert int(got.step) == 10  # rewires at steps 0, 3, 6 and 9
     assert (np.asarray(got.plast.hcu_mask) != np.asarray(st0.plast.hcu_mask)).any()

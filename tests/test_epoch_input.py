@@ -3,10 +3,8 @@ and every shuffled epoch is gathered there (``ScanPlan.stages`` /
 ``ScanPlan.stage``, ``program._stage``).  The states are bit-identical to the
 host gather's; the history shows each staging and where each epoch gathered;
 a device without room keeps the host gather; the activation store stays keyed
-on the caller's array."""
-import os
-import subprocess
-import sys
+on the caller's array; under a trainer one row rule places the staged set and
+the store's levels alike."""
 import textwrap
 
 import jax
@@ -25,7 +23,6 @@ from repro.core import (
 from repro.data import complementary_code, mnist_like
 from repro.runtime import plans
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 H1, H2 = UnitLayout(4, 8), UnitLayout(3, 4)
 
 
@@ -71,30 +68,24 @@ def _fit(layout, x, y, readout="bcpnn", **config):
 
 
 @pytest.mark.parametrize("readout", ["bcpnn", "sgd"])
-@pytest.mark.parametrize("cache", [True, False])
-def test_staged_states_bit_identical(dataset, monkeypatch, readout, cache):
+def test_staged_states_bit_identical(dataset, monkeypatch, readout):
     x, y, layout = dataset
-    staged, _ = _fit(layout, x, y, readout, cache_activations=cache)
-    on_device, _ = _fit(layout, jnp.asarray(x), jnp.asarray(y), readout,
-                        cache_activations=cache)
+    staged, _ = _fit(layout, x, y, readout)
+    on_device, _ = _fit(layout, jnp.asarray(x), jnp.asarray(y), readout)
     monkeypatch.setattr(plans, "free_device_bytes", lambda devices: 0)
-    host, res = _fit(layout, x, y, readout, cache_activations=cache)
+    host, res = _fit(layout, x, y, readout)
     assert {h["input"] for h in res.history if "epoch" in h} == {"host"}
     _assert_same(staged, on_device)
     _assert_same(staged, host)
 
 
-@pytest.mark.parametrize("cache", [True, False])
-def test_history_has_one_stage_per_host_array(dataset, cache):
+def test_history_has_one_stage_per_host_array(dataset):
     x, y, layout = dataset
-    _, res = _fit(layout, x, y, cache_activations=cache)
+    _, res = _fit(layout, x, y)
     stages = [h for h in res.history if h["phase"] == "stage"]
-    # hidden0 stages x; the readout stages its x (level 0 only on the fused
-    # path: the cached path reads the device-resident level-1 projection)
-    # and y.
-    want = [(0, "x", x.nbytes)]
-    want += [(0, "x", x.nbytes)] if not cache else []
-    want += [(0, "y", y.nbytes)]
+    # hidden0 stages x; the readout reads the device-resident level-1
+    # projection and stages y.
+    want = [(0, "x", x.nbytes), (0, "y", y.nbytes)]
     assert [(h["level"], h["array"], h["bytes"]) for h in stages] == want
     for h in stages:
         assert h["seconds"] == pytest.approx(
@@ -181,57 +172,74 @@ def test_projection_cache_keyed_on_the_callers_array(dataset):
     assert {h["input"] for h in res.history if "epoch" in h} == {"device"}
 
 
+def _on_8_devices(*parts: str) -> str:
+    """The code ``parts`` run in a fresh interpreter that sees 8 forced CPU
+    devices (jax fixes the device count at start-up); its stdout."""
+    from tests.test_distributed import run_with_devices
+
+    return run_with_devices("\n".join(textwrap.dedent(p) for p in parts))
+
+
+# A (4, 2) data x model mesh of 8 forced CPU devices and a one-hidden-layer
+# network with a BCPNN readout, shared by the trainer tests below.
+TRAINER = """
+    import jax, numpy as np
+    from repro.core import (DenseLayer, ExecutionConfig, Network,
+                            StructuralPlasticityLayer, UnitLayout,
+                            onehot_layout)
+    from repro.core.distributed import DataParallelTrainer
+    from repro.data import complementary_code, mnist_like
+    from repro.runtime import plans
+
+    ds = mnist_like(n_train=256, n_test=32, n_features=16, seed=0)
+    x, layout = complementary_code(ds.x_train)
+    mesh = jax.make_mesh((4, 2), ("data", "model"))
+
+    def compile_net(trainer=None):
+        hidden = UnitLayout(4, 8)
+        net = Network(seed=0)
+        net.add(StructuralPlasticityLayer(layout, hidden, fan_in=8,
+                                          lam=0.05, init_jitter=1.0))
+        net.add(DenseLayer(hidden, onehot_layout(10), lam=0.05))
+        return net.compile(ExecutionConfig(trainer=trainer))
+
+    def trainer():
+        return DataParallelTrainer(mesh, mode="shard_map")
+"""
+
+
 def test_data_parallel_staging_matches_host_gather():
     """shard_map data parallelism on 8 forced CPU devices (a (4, 2) mesh):
-    rows sharded over ``data`` (256 rows, cached path), replicated where
-    they do not divide (250 rows, fused path: the activation store cannot
-    row-shard its level-1 cache of 250 rows either); both bit-identical to
-    the host gather.  The guard counts what the compiled gather holds on a
-    device, more than the row shard and the stack's share of it, so it
-    refuses free memory that a count of the shard would have passed."""
-    code = textwrap.dedent("""
-        import jax, numpy as np
-        from repro.core import (DenseLayer, ExecutionConfig, Network,
-                                StructuralPlasticityLayer, UnitLayout,
-                                onehot_layout)
-        from repro.core.distributed import DataParallelTrainer
-        from repro.data import complementary_code, mnist_like
-        from repro.runtime import plans
-
-        ds = mnist_like(n_train=256, n_test=32, n_features=16, seed=0)
-        x, layout = complementary_code(ds.x_train)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-
-        def fit(n, cache):
-            hidden = UnitLayout(4, 8)
-            net = Network(seed=0)
-            net.add(StructuralPlasticityLayer(layout, hidden, fan_in=8,
-                                              lam=0.05, init_jitter=1.0))
-            net.add(DenseLayer(hidden, onehot_layout(10), lam=0.05))
-            tr = DataParallelTrainer(mesh, mode="shard_map")
-            compiled = net.compile(ExecutionConfig(trainer=tr,
-                                                   cache_activations=cache))
+    rows sharded over ``data`` (256 rows), a whole copy on every chip where
+    they do not divide (250 rows: the staged set and the level-1 projection
+    alike); both bit-identical to the host gather.  The guard counts what
+    the compiled gather holds on a device, more than the row shard and the
+    stack's share of it, so it refuses free memory that a count of the
+    shard would have passed."""
+    out = _on_8_devices(TRAINER, """
+        def fit(n):
+            compiled = compile_net(trainer())
             res = compiled.fit((x[:n], ds.y_train[:n]), epochs_hidden=2,
                                epochs_readout=1, batch_size=64)
             leaves = [np.asarray(jax.device_get(a))
                       for a in jax.tree_util.tree_leaves(compiled.state)]
             return leaves, res.history
 
-        for n, cache in ((256, True), (250, False)):
-            staged, hist = fit(n, cache)
+        for n in (256, 250):
+            staged, hist = fit(n)
             arrays = [h["array"] for h in hist if h["phase"] == "stage"]
-            assert arrays == (["x", "y"] if cache else ["x", "x", "y"]), hist
+            assert arrays == ["x", "y"], hist
             assert {h["input"] for h in hist if "epoch" in h} == {"device"}
             real = plans.free_device_bytes
             plans.free_device_bytes = lambda devices: 0
-            host, hist = fit(n, cache)
+            host, hist = fit(n)
             plans.free_device_bytes = real
             assert {h["input"] for h in hist if "epoch" in h} == {"host"}
             for a, b in zip(staged, host):
                 np.testing.assert_array_equal(a, b)
             print(n, "OK")
 
-        tr = DataParallelTrainer(mesh, mode="shard_map")
+        tr = trainer()
         plan = Network(seed=0).add(DenseLayer(layout, onehot_layout(10))) \
             .compile(ExecutionConfig(trainer=tr)).plan
         x = np.asarray(x, np.float32)
@@ -245,13 +253,58 @@ def test_data_parallel_staging_matches_host_gather():
         assert plan.stages(x, 256, 64)
         print("guard OK")
     """)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=560)
-    assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
-    assert out.stdout.split() == ["256", "OK", "250", "OK", "guard", "OK"]
+    assert out.split() == ["256", "OK", "250", "OK", "guard", "OK"]
+
+
+def test_stage_and_store_place_rows_alike():
+    """The trainer's one row rule (``rows_sharding``) places a staged
+    dataset and an activation-store level the same way: rows sharded over
+    ``data`` at 256 rows, a whole copy on every chip at 250."""
+    out = _on_8_devices(TRAINER, """
+        compiled = compile_net(trainer())
+        tr = compiled.config.trainer
+        for n in (256, 250):
+            h = compiled.activations.level(1, list(compiled.state.layers),
+                                           x[:n], chunk=64)
+            staged = compiled.plan.stage(np.zeros(h.shape, np.float32))
+            assert h.sharding == staged.sharding == tr.rows_sharding(h.shape)
+            rows = staged.sharding.shard_shape(h.shape)[0]
+            assert rows == (64 if n == 256 else 250), rows
+            assert staged.sharding.is_fully_replicated == (n == 250)
+            print(n, "OK")
+    """)
+    assert out.split() == ["256", "OK", "250", "OK"]
+
+
+@pytest.mark.parametrize("readout", ["bcpnn", "sgd"])
+def test_readout_under_a_trainer_on_rows_that_do_not_divide(readout):
+    """``fit`` with a readout under a shard_map trainer on 250 rows, which
+    the (4, 2) mesh's four batch shards do not divide: the level-1
+    projection the readout trains on is placed as the staged set is (a
+    whole copy on every chip), and the states match one device within the
+    tolerances of ``test_deep_trainer_shard_map_parity``."""
+    out = _on_8_devices(TRAINER, f"""
+        kw = dict(epochs_hidden=2, epochs_readout=1, batch_size=64,
+                  readout={readout!r}, shuffle=False)
+        data = (x[:250], ds.y_train[:250])
+        ref = compile_net()
+        ref.fit(data, **kw)
+        dp = compile_net(trainer())
+        dp.fit(data, **kw)
+        close = lambda a, b, **tol: np.testing.assert_allclose(
+            np.asarray(jax.device_get(a)), np.asarray(b), **tol)
+        for sa, sb in zip(dp.state.layers, ref.state.layers):
+            close(sa.w, sb.w, rtol=2e-4, atol=2e-5)
+            close(sa.marginals.cij, sb.marginals.cij, rtol=2e-4, atol=1e-7)
+            assert int(sa.step) == int(sb.step)
+        assert int(dp.state.layers[-1].step) == (3 if {readout!r} == "bcpnn" else 0)
+        if {readout!r} == "sgd":
+            for k in ("w", "b"):
+                close(dp.state.readout[k], ref.state.readout[k],
+                      rtol=2e-4, atol=2e-5)
+        print("OK")
+    """)
+    assert out.split() == ["OK"]
 
 
 def test_spilled_level_stays_with_the_store(dataset):

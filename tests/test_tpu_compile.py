@@ -24,7 +24,7 @@ from repro.kernels.bcpnn_phase import bcpnn_phase_fused
 from repro.kernels.bcpnn_update import bcpnn_update_fused
 from repro.kernels.hcu_softmax import hcu_softmax
 from repro.kernels.masked_matmul import masked_matmul
-from repro.runtime import hidden_epoch_cached_fn
+from repro.runtime import hidden_epoch_fn
 
 # name -> (B, F, n_hcu, n_mcu)
 WIDTHS = {
@@ -171,7 +171,7 @@ def test_hidden_epoch_loop_builds_no_unit_mask(one_chip, width):
         jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0))),
     )
     xs = _shapes(one_chip)(4, B, F)
-    hlo = hidden_epoch_cached_fn(layer).lower(state, xs).compile().as_text()
+    hlo = hidden_epoch_fn(layer).lower(state, xs).compile().as_text()
     unit_elems = F * n_hcu * n_mcu
     moves = ("broadcast", "reshape", "copy", "copy-start", "transpose", "gather")
     found = []

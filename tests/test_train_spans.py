@@ -6,7 +6,6 @@ import sys
 
 import jax
 import numpy as np
-import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -82,7 +81,7 @@ def test_traces_counts_compiles_per_epoch():
     assert epochs[0]["traces"] >= 1 and epochs[1]["traces"] == 0
     dispatch = compiled.tracer.spans("train.dispatch")
     assert [s.attrs["traces"] for s in dispatch] == [1, 0]
-    assert "hidden_epoch_cached[0]" in compiled.plan.jit_cache_sizes()
+    assert "hidden_epoch[0]" in compiled.plan.jit_cache_sizes()
 
 
 def test_states_bit_identical_with_tracer_on_and_off():
@@ -94,19 +93,16 @@ def test_states_bit_identical_with_tracer_on_and_off():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("cache,names", [
-    (True, {"hidden_epoch_cached", "readout_epoch_cached"}),
-    (False, {"hidden_epoch", "readout_epoch"}),
-])
-def test_hidden_and_readout_scans_have_their_own_names(cache, names):
+def test_hidden_and_readout_scans_have_their_own_names():
     ds = mnist_like(n_train=128, n_test=32, n_features=32, seed=0)
     x, layout = complementary_code(ds.x_train)
     hidden = UnitLayout(4, 8)
     net = Network(seed=0).add(
         StructuralPlasticityLayer(layout, hidden, fan_in=16, lam=0.05)
     ).add(DenseLayer(hidden, onehot_layout(10), lam=0.05))
-    compiled = net.compile(ExecutionConfig(cache_activations=cache))
+    compiled = net.compile(ExecutionConfig())
     compiled.fit((np.asarray(x, np.float32), ds.y_train), epochs_hidden=1,
                  epochs_readout=1, batch_size=64)
     # jax.jit names the program jit_<__name__>
-    assert {fn.__name__ for fn in compiled.plan.jitted.values()} == names
+    assert {fn.__name__ for fn in compiled.plan.jitted.values()} == {
+        "hidden_epoch", "readout_epoch"}
